@@ -15,7 +15,7 @@ import dataclasses
 import tempfile
 
 # 1. a clustered corpus + production-like queries (per-query top-k)
-spec = dataclasses.replace(PAPER_DATASETS["sift"], n=20_000, dim=32)
+spec = dataclasses.replace(PAPER_DATASETS["sift"], n=20_000)   # 128-d
 x = make_vectors(spec)
 queries, topk = make_queries(spec, 256)
 topk = np.minimum(topk, 50).astype(np.int32)

@@ -30,21 +30,34 @@ import jax.numpy as jnp
 from repro.kernels import ops as kops
 
 
+def _bucketed(x: np.ndarray) -> tuple[jax.Array, jax.Array]:
+    """(points padded to a power-of-two row bucket, live count).  The
+    recursive splitter runs k-means at a different n for every tree node;
+    padding makes one compiled assign program serve a whole bucket (the
+    fused kernel treats rows past the live count as dead)."""
+    n = x.shape[0]
+    nb = max(256, 1 << (n - 1).bit_length())
+    return jnp.asarray(np.pad(x, ((0, nb - n), (0, 0)))), jnp.int32(n)
+
+
 def kmeans_assign_step(
     x: np.ndarray, cents: np.ndarray, fused: bool = False
 ) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray]:
     """One Lloyd E+M data pass. Returns (assign (N,) i64, min_dist (N,) f32,
     sums (K, D), counts (K,) i64).
 
-    Fused: a single device pass (kernel/oracle) returns everything; counts
-    come back exact (integer cross-chunk fold).  Unfused: device argmin +
-    host float64 scatter-add — the legacy reference the bench pairs against.
+    Fused: a single device pass (kernel/oracle) over the row-bucketed
+    points returns everything; counts come back exact (integer cross-chunk
+    fold).  Unfused: device argmin + host float64 scatter-add — the legacy
+    reference the bench pairs against.
     """
     k, d = cents.shape
     if fused:
+        n = x.shape[0]
+        xd, nv = _bucketed(x)
         a, md, sums, counts = kops.kmeans_assign_update(
-            jnp.asarray(x), jnp.asarray(cents))
-        return (np.asarray(a, np.int64), np.asarray(md),
+            xd, jnp.asarray(cents), n_valid=nv)
+        return (np.asarray(a, np.int64)[:n], np.asarray(md)[:n],
                 np.asarray(sums, np.float64),
                 np.asarray(counts, np.int64))
     a, md = kops.kmeans_assign(jnp.asarray(x), jnp.asarray(cents))
@@ -76,19 +89,18 @@ def kmeans(
     rng = np.random.default_rng(seed)
     cents = x[rng.choice(n, size=k, replace=False)].astype(np.float32).copy()
     if fused and device_mstep:
-        xd = jnp.asarray(x)
+        xd, nv = _bucketed(x)       # padding rows: no sums, min-dist -inf
         cd = jnp.asarray(cents)
-        a = jnp.zeros((n,), jnp.int32)
-        md = jnp.zeros((n,), jnp.float32)
         for _ in range(max(1, iters)):
-            a, md, sums, counts = kops.kmeans_assign_update(xd, cd)
+            a, md, sums, counts = kops.kmeans_assign_update(xd, cd,
+                                                            n_valid=nv)
             # worst-served candidates for however many clusters come up
             # empty (ties resolve by lowest index — top_k order, the
             # canonical semantics kmeans_mstep documents)
             _, worst = jax.lax.top_k(md, k)
             cd = kops.kmeans_mstep(sums, counts, xd[worst])
-        return (np.asarray(cd), np.asarray(a, np.int32),
-                float(np.asarray(md).sum()))
+        return (np.asarray(cd), np.asarray(a, np.int32)[:n],
+                float(np.asarray(md)[:n].sum()))
     assign = np.zeros(n, np.int64)
     mind = np.zeros(n, np.float32)
     for _ in range(max(1, iters)):

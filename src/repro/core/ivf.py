@@ -114,9 +114,13 @@ def make_group_quantizer(
 def brute_force_topk(
     x: jax.Array, queries: jax.Array, k: int, chunk: int = 8192
 ) -> tuple[jax.Array, jax.Array]:
-    """Exact ground truth: (B, k) distances + ids over the raw vectors."""
-    d = squared_l2_chunked(queries, x, chunk=chunk)
-    return topk_smallest(d, k)
+    """Exact ground truth: (B, k) distances + ids over the raw vectors.
+
+    The inner products run at HIGHEST matmul precision: the TPU's default
+    rounds f32 operands to bf16, which reorders near neighbours."""
+    with jax.default_matmul_precision("highest"):
+        d = squared_l2_chunked(queries, x, chunk=chunk)
+        return topk_smallest(d, k)
 
 
 def search_flat(

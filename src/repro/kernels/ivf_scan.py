@@ -52,6 +52,15 @@ to HBM:
 The data-dependent block index (which cluster to DMA) uses Pallas scalar
 prefetch: the per-tile block table is a scalar-prefetch operand consumed by
 the BlockSpec index_map — the same mechanism as paged-attention block tables.
+
+Mosaic tiling rule: the last two dims of every block must be divisible by
+(8, 128) or equal the array's.  Per-cluster rows of a 2-D table (posting
+ids, centroids, norms) are therefore DMA'd as the (8, X) sublane tile that
+holds the row, and the kernel picks the row with a dynamic sublane slice
+(:func:`_pick_row`); the tables keep their (C, X) layout, so no padded copy
+is ever made.  The per-tile query-selection mask rides in as one
+(bq, S) block per tile and the kernel selects step ``s``'s column with a
+lane mask.
 """
 from __future__ import annotations
 
@@ -64,24 +73,52 @@ from jax.experimental.pallas import tpu as pltpu
 
 
 # --------------------------------------------------------------------------
+# shared in-kernel helpers
+# --------------------------------------------------------------------------
+def _row_spec(n_rows: int, width: int, row_of):
+    """BlockSpec DMAing the (rb, width) tile that holds row ``row_of(...)``:
+    one sublane tile (rb = 8), or the whole table when it is shorter (block
+    dim == array dim).  Pair with :func:`_pick_row` in the kernel."""
+    rb = min(8, n_rows)
+    return pl.BlockSpec((rb, width), lambda *a: (row_of(*a) // rb, 0))
+
+
+def _pick_row(ref, row):
+    """Row ``row`` of a table DMA'd through :func:`_row_spec`, as (1, X)."""
+    return ref[pl.ds(row % ref.shape[0], 1), :]
+
+
+def _dot_t(a, b):
+    """(n, D) x (m, D) -> (n, m) on the MXU at full f32 precision."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST)
+
+
+def _sq_norms_row(blk):
+    """(L, D) -> (1, L) squared row norms, lane-major (an MXU matvec, so no
+    sublane->lane relayout is needed)."""
+    ones = jnp.ones((1, blk.shape[1]), jnp.float32)
+    return _dot_t(ones, blk * blk)
+
+
+def _l2_tile(q, blk):
+    """(n, D) x (L, D) -> (n, L) squared L2 clamped at 0 — one MXU matmul."""
+    d = (jnp.sum(q * q, axis=1, keepdims=True) - 2.0 * _dot_t(q, blk)
+         + _sq_norms_row(blk))
+    return jnp.maximum(d, 0.0)
+
+
+# --------------------------------------------------------------------------
 # legacy full-distance kernels
 # --------------------------------------------------------------------------
 def _qmajor_kernel(cids_ref, mask_ref, q_ref, post_ref, o_ref):
     b = pl.program_id(0)
     p = pl.program_id(1)
-    q = q_ref[...].astype(jnp.float32)            # (1, D)
-    blk = post_ref[0].astype(jnp.float32)         # (L, D)
-    # ||q||^2 - 2 q.blk^T + ||blk||^2  -> (1, L)
-    d = (
-        jnp.sum(q * q)
-        - 2.0 * jax.lax.dot_general(
-            q, blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        + jnp.sum(blk * blk, axis=1)[None, :]
-    )
-    d = jnp.maximum(d, 0.0)
+    q = _pick_row(q_ref, b).astype(jnp.float32)        # (1, D)
+    d = _l2_tile(q, post_ref[0].astype(jnp.float32))   # (1, L)
     live = mask_ref[b, p] > 0
-    o_ref[...] = jnp.where(live, d[:, None, :], jnp.inf)
+    o_ref[0, pl.ds(p, 1), :] = jnp.where(live, d, jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -93,7 +130,10 @@ def ivf_scan(
     *,
     interpret: bool = False,
 ) -> jax.Array:
-    """Returns (B, P, L) f32 distances; masked probes +inf.  (Legacy path.)"""
+    """Returns (B, P, L) f32 distances; masked probes +inf.  (Legacy path.)
+
+    The (1, P, L) output block of query b stays resident across its P probe
+    steps (revisited-output pattern); each step writes one row."""
     C, L, D = postings.shape
     B, P = cids.shape
     safe_cids = jnp.clip(cids, 0, C - 1).astype(jnp.int32)
@@ -103,10 +143,10 @@ def ivf_scan(
         num_scalar_prefetch=2,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, D), lambda b, p, cids_p, mask_p: (b, 0)),
+            _row_spec(B, D, lambda b, p, cids_p, mask_p: b),
             pl.BlockSpec((1, L, D), lambda b, p, cids_p, mask_p: (cids_p[b, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, L), lambda b, p, cids_p, mask_p: (b, p, 0)),
+        out_specs=pl.BlockSpec((1, P, L), lambda b, p, cids_p, mask_p: (b, 0, 0)),
     )
     return pl.pallas_call(
         _qmajor_kernel,
@@ -118,17 +158,10 @@ def ivf_scan(
 
 def _cmajor_kernel(active_ref, qsel_ref, q_ref, post_ref, o_ref):
     a = pl.program_id(0)
-    blk = post_ref[...].astype(jnp.float32)[0]    # (L, D)
+    blk = post_ref[0].astype(jnp.float32)         # (L, D)
     q = q_ref[...].astype(jnp.float32)            # (B, D)
-    d = (
-        jnp.sum(blk * blk, axis=1)[:, None]
-        - 2.0 * jax.lax.dot_general(
-            blk, q, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        + jnp.sum(q * q, axis=1)[None, :]
-    )                                             # (L, B) — one MXU matmul
-    d = jnp.maximum(d, 0.0)
-    sel = qsel_ref[a, :][None, :] > 0             # (1, B)
+    d = _l2_tile(blk, q)                          # (L, B) — one MXU matmul
+    sel = _pick_row(qsel_ref, a) > 0              # (1, B)
     o_ref[...] = jnp.where(sel, d, jnp.inf)[None]
 
 
@@ -149,13 +182,14 @@ def ivf_scan_clustermajor(
     qsel_i = qsel.astype(jnp.int32)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=1,
         grid=(A,),
         in_specs=[
-            pl.BlockSpec((B, D), lambda a, act_p, qsel_p: (0, 0)),
-            pl.BlockSpec((1, L, D), lambda a, act_p, qsel_p: (act_p[a], 0, 0)),
+            _row_spec(A, B, lambda a, act_p: a),
+            pl.BlockSpec((B, D), lambda a, act_p: (0, 0)),
+            pl.BlockSpec((1, L, D), lambda a, act_p: (act_p[a], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, L, B), lambda a, act_p, qsel_p: (a, 0, 0)),
+        out_specs=pl.BlockSpec((1, L, B), lambda a, act_p: (a, 0, 0)),
     )
     return pl.pallas_call(
         _cmajor_kernel,
@@ -230,33 +264,52 @@ def plan_tile_probes(
     return tile_cids, qsel
 
 
-def _extract_topk(cat_d: jax.Array, cat_i: jax.Array, k2: int):
-    """k2-pass min-extraction with duplicate-id suppression.
+def _extract_topk(acc_d, acc_i, new_d, new_i, k2: int):
+    """k2-pass min-extraction with duplicate-id suppression over the running
+    accumulator (bq, k2) followed by a fresh block (bq, L).
 
-    cat_d, cat_i: (bq, n).  Returns ((bq, k2) dists ascending, (bq, k2) ids);
-    exhausted slots are (+inf, -1).  Each pass takes the global min, emits it,
-    and kills every remaining entry carrying the same id — so the output is
-    unique-by-id with the per-id MIN distance (dedup-top-k semantics; closure
-    duplicates of one vector collapse to a single candidate).
-    """
-    bq, n = cat_d.shape
-    col = jax.lax.broadcasted_iota(jnp.int32, (bq, n), 1)
-    out_d, out_i = [], []
-    for _ in range(k2):
-        m = jnp.min(cat_d, axis=1, keepdims=True)                 # (bq, 1)
-        pos = jnp.min(jnp.where(cat_d == m, col, n), axis=1, keepdims=True)
-        hit = col == pos                                          # one-hot
-        pid = jnp.sum(jnp.where(hit, cat_i, 0), axis=1, keepdims=True)
-        ok = jnp.isfinite(m)
-        out_d.append(jnp.where(ok, m, jnp.inf)[:, 0])
-        out_i.append(jnp.where(ok, pid, -1)[:, 0])
-        kill = hit | ((cat_i == pid) & (pid >= 0) & ok)
-        cat_d = jnp.where(kill, jnp.inf, cat_d)
-    return jnp.stack(out_d, axis=1), jnp.stack(out_i, axis=1).astype(jnp.int32)
+    Returns ((bq, k2) dists ascending, (bq, k2) ids); exhausted slots are
+    (+inf, -1).  Each pass takes the global min (ties resolve to the
+    accumulator first, then the lowest column — the order of the two parts
+    laid side by side), emits it, and kills every remaining entry carrying
+    the same id — so the output is unique-by-id with the per-id MIN distance
+    (dedup-top-k semantics; closure duplicates of one vector collapse to a
+    single candidate).  The two parts are never concatenated: a lane concat
+    at an unaligned k2 is a relayout Mosaic need not support, and the
+    emitted column is written with a lane mask for the same reason."""
+    bq = acc_d.shape[0]
+    acol = jax.lax.broadcasted_iota(jnp.int32, acc_d.shape, 1)
+    ncol = jax.lax.broadcasted_iota(jnp.int32, new_d.shape, 1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (bq, k2), 1)
+    none = acc_d.shape[1] + new_d.shape[1]
+    out_d = jnp.full((bq, k2), jnp.inf, jnp.float32)
+    out_i = jnp.full((bq, k2), -1, jnp.int32)
+    for j in range(k2):
+        m = jnp.minimum(jnp.min(acc_d, axis=1, keepdims=True),
+                        jnp.min(new_d, axis=1, keepdims=True))   # (bq, 1)
+        apos = jnp.min(jnp.where(acc_d == m, acol, none), axis=1,
+                       keepdims=True)
+        npos = jnp.min(jnp.where(new_d == m, ncol, none), axis=1,
+                       keepdims=True)
+        ahit = acol == apos                                      # one-hot
+        nhit = (ncol == npos) & (apos == none)
+        pid = (jnp.sum(jnp.where(ahit, acc_i, 0), axis=1, keepdims=True)
+               + jnp.sum(jnp.where(nhit, new_i, 0), axis=1, keepdims=True))
+        ok = m < jnp.inf
+        out_d = jnp.where((kcol == j) & ok, m, out_d)
+        out_i = jnp.where((kcol == j) & ok, pid, out_i)
+        dup = (pid >= 0) & ok
+        acc_d = jnp.where(ahit | ((acc_i == pid) & dup), jnp.inf, acc_d)
+        new_d = jnp.where(nhit | ((new_i == pid) & dup), jnp.inf, new_d)
+    return out_d, out_i
 
 
-def _qtile_topk_kernel(tc_ref, q_ref, pids_ref, qsel_ref, post_ref,
-                       od_ref, oi_ref):
+def _merge_block(d, pids, qsel_ref, od_ref, oi_ref):
+    """Fold one scanned (bq, L) distance block into the resident (bq, k2)
+    candidate accumulator.  ``pids`` (1, L) are the block's global ids;
+    ``qsel_ref`` is the tile's (1, bq, S) query-selection block, whose
+    column ``s`` (this grid step) says which queries of the tile probed the
+    block."""
     s = pl.program_id(1)
 
     @pl.when(s == 0)
@@ -264,25 +317,46 @@ def _qtile_topk_kernel(tc_ref, q_ref, pids_ref, qsel_ref, post_ref,
         od_ref[...] = jnp.full(od_ref.shape, jnp.inf, od_ref.dtype)
         oi_ref[...] = jnp.full(oi_ref.shape, -1, oi_ref.dtype)
 
-    q = q_ref[...].astype(jnp.float32)                  # (bq, D)
-    blk = post_ref[0].astype(jnp.float32)               # (L, D)
-    d = (
-        jnp.sum(q * q, axis=1, keepdims=True)
-        - 2.0 * jax.lax.dot_general(
-            q, blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        + jnp.sum(blk * blk, axis=1)[None, :]
-    )                                                   # (bq, L) — one MXU op
-    d = jnp.maximum(d, 0.0)
-    bq = d.shape[0]
-    sel = jnp.reshape(qsel_ref[...], (bq, 1)) > 0       # (bq, 1)
-    ids = jnp.broadcast_to(pids_ref[...], d.shape).astype(jnp.int32)
+    qs = qsel_ref[0]                                             # (bq, S)
+    scol = jax.lax.broadcasted_iota(jnp.int32, qs.shape, 1)
+    sel = jnp.max(jnp.where(scol == s, qs, 0), axis=1, keepdims=True) > 0
+    ids = jnp.broadcast_to(pids.astype(jnp.int32), d.shape)
     d = jnp.where(sel & (ids >= 0), d, jnp.inf)
-    cat_d = jnp.concatenate([od_ref[...], d], axis=1)
-    cat_i = jnp.concatenate([oi_ref[...], ids], axis=1)
-    nd, ni = _extract_topk(cat_d, cat_i, od_ref.shape[-1])
+    nd, ni = _extract_topk(od_ref[...], oi_ref[...], d, ids,
+                           od_ref.shape[-1])
     od_ref[...] = nd
     oi_ref[...] = ni
+
+
+def _qtile_topk_kernel(tc_ref, q_ref, pids_ref, qsel_ref, post_ref,
+                       od_ref, oi_ref):
+    c = tc_ref[pl.program_id(0), pl.program_id(1)]
+    d = _l2_tile(q_ref[...].astype(jnp.float32),
+                 post_ref[0].astype(jnp.float32))       # (bq, L) — one MXU op
+    _merge_block(d, _pick_row(pids_ref, c), qsel_ref, od_ref, oi_ref)
+
+
+def tile_plan(cids, mask, queries, bq: int, n_clusters: int):
+    """Pad the batch to the query tile and build the probe plan shared by
+    both fused kernels.  Returns (queries (bp, D), tile_cids (nb, S),
+    qsel (nb, bq, S) int32) — qsel transposed so one (bq, S) block per
+    tile is DMA'd once and stays resident across the tile's S steps."""
+    padb = (-cids.shape[0]) % bq
+    if padb:
+        queries = jnp.pad(queries, ((0, padb), (0, 0)))
+        cids = jnp.pad(cids, ((0, padb), (0, 0)))
+        mask = jnp.pad(jnp.asarray(mask, bool), ((0, padb), (0, 0)))
+    tile_cids, qsel = plan_tile_probes(cids, mask, bq, n_clusters)
+    return queries, tile_cids, jnp.swapaxes(qsel, 1, 2)
+
+
+def topk_outputs(nb: int, bq: int, k2: int):
+    """(out_specs, out_shape) of the fused kernels: one (bq, k2) candidate
+    accumulator block per tile for dists and ids, revisited across the
+    tile's probe steps."""
+    spec = pl.BlockSpec((bq, k2), lambda t, s, *_: (t, 0))
+    return [spec, spec], (jax.ShapeDtypeStruct((nb * bq, k2), jnp.float32),
+                          jax.ShapeDtypeStruct((nb * bq, k2), jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("k2", "bq", "interpret"))
@@ -304,38 +378,26 @@ def ivf_scan_topk(
     (B, P, L) distance tensor.
     """
     C, L, D = postings.shape
-    B, P = cids.shape
-    padb = (-B) % bq
-    if padb:
-        queries = jnp.pad(queries, ((0, padb), (0, 0)))
-        cids = jnp.pad(cids, ((0, padb), (0, 0)))
-        mask = jnp.pad(jnp.asarray(mask, bool), ((0, padb), (0, 0)))
-    bp = B + padb
-    nb = bp // bq
-    s_len = bq * P
-    tile_cids, qsel = plan_tile_probes(cids, mask, bq, C)
+    B = cids.shape[0]
+    queries, tile_cids, qsel = tile_plan(cids, mask, queries, bq, C)
+    nb, s_len = tile_cids.shape
+    out_specs, out_shape = topk_outputs(nb, bq, k2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nb, s_len),
         in_specs=[
             pl.BlockSpec((bq, D), lambda t, s, tc: (t, 0)),
-            pl.BlockSpec((1, L), lambda t, s, tc: (tc[t, s], 0)),
-            pl.BlockSpec((1, 1, bq), lambda t, s, tc: (t, s, 0)),
+            _row_spec(C, L, lambda t, s, tc: tc[t, s]),
+            pl.BlockSpec((1, bq, s_len), lambda t, s, tc: (t, 0, 0)),
             pl.BlockSpec((1, L, D), lambda t, s, tc: (tc[t, s], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bq, k2), lambda t, s, tc: (t, 0)),
-            pl.BlockSpec((bq, k2), lambda t, s, tc: (t, 0)),
-        ],
+        out_specs=out_specs,
     )
     od, oi = pl.pallas_call(
         _qtile_topk_kernel,
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((bp, k2), jnp.float32),
-            jax.ShapeDtypeStruct((bp, k2), jnp.int32),
-        ),
+        out_shape=out_shape,
         interpret=interpret,
     )(tile_cids, queries, posting_ids.astype(jnp.int32), qsel, postings)
     return od[:B], oi[:B]

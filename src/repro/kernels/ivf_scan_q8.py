@@ -9,7 +9,10 @@ and applies the closed-form residual expansion:
     ||q - (c + s r8)||^2 = ||q - c||^2 - 2 s (q - c).r8 + s^2 ||r8||^2
 
 Operands per grid step: q8 block (L, D) int8, centroid row (D,), per-cluster
-scale, precomputed s^2||r8||^2 row (L,).
+scale, precomputed s^2||r8||^2 row (L,).  Centroid and norm rows arrive as
+the (8, X) tile that holds them (see kernels/ivf_scan.py on the Mosaic
+tiling rule); the per-step scale is gathered on the host side of the call
+into a scalar-prefetch table, so it is read from SMEM as a scalar.
 
 Two variants:
 
@@ -28,26 +31,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .ivf_scan import _extract_topk, plan_tile_probes
+from .ivf_scan import (
+    _dot_t, _merge_block, _pick_row, _row_spec, tile_plan, topk_outputs,
+)
 
 
-def _kernel(cids_ref, mask_ref, q_ref, cent_ref, scale_ref, norm2_ref,
+def _residual_l2(q, cent, r8, scale, n2):
+    """||q - (c + s r8)||^2 = ||q - c||^2 - 2 s (q - c).r8 + s^2 ||r8||^2
+    for (n, D) queries against one (L, D) int8 block -> (n, L), clamped."""
+    qc = q - cent                                  # (n, D)
+    cross = _dot_t(qc, r8.astype(jnp.float32))     # (n, L) — one MXU op
+    d = jnp.sum(qc * qc, axis=1, keepdims=True) - 2.0 * scale * cross + n2
+    return jnp.maximum(d, 0.0)
+
+
+def _kernel(cids_ref, mask_ref, scale_ref, q_ref, cent_ref, norm2_ref,
             q8_ref, o_ref):
     b = pl.program_id(0)
     p = pl.program_id(1)
-    q = q_ref[...].astype(jnp.float32)             # (1, D)
-    cent = cent_ref[...].astype(jnp.float32)       # (1, D)
-    r8 = q8_ref[0].astype(jnp.float32)             # (L, D)
-    s = scale_ref[0, 0].astype(jnp.float32)        # ()
-    n2 = norm2_ref[...].astype(jnp.float32)        # (1, L)
-    qc = q - cent                                  # (1, D)
-    cross = jax.lax.dot_general(
-        qc, r8, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                              # (1, L)
-    d = jnp.sum(qc * qc) - 2.0 * s * cross + n2
-    d = jnp.maximum(d, 0.0)
+    c = cids_ref[b, p]
+    d = _residual_l2(_pick_row(q_ref, b).astype(jnp.float32),
+                     _pick_row(cent_ref, c).astype(jnp.float32), q8_ref[0],
+                     scale_ref[b, p], _pick_row(norm2_ref, c))   # (1, L)
     live = mask_ref[b, p] > 0
-    o_ref[...] = jnp.where(live, d[:, None, :], jnp.inf)
+    o_ref[0, pl.ds(p, 1), :] = jnp.where(live, d, jnp.inf)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -67,59 +74,39 @@ def ivf_scan_q8(
     B, P = cids.shape
     safe = jnp.clip(cids, 0, C - 1).astype(jnp.int32)
     mask_i = mask.astype(jnp.int32)
+    step_scale = scale.reshape(C).astype(jnp.float32)[safe]      # (B, P)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, P),
         in_specs=[
-            pl.BlockSpec((1, D), lambda b, p, c_p, m_p: (b, 0)),
-            pl.BlockSpec((1, D), lambda b, p, c_p, m_p: (c_p[b, p], 0)),
-            pl.BlockSpec((1, 1, 1), lambda b, p, c_p, m_p: (c_p[b, p], 0, 0)),
-            pl.BlockSpec((1, L), lambda b, p, c_p, m_p: (c_p[b, p], 0)),
-            pl.BlockSpec((1, L, D), lambda b, p, c_p, m_p: (c_p[b, p], 0, 0)),
+            _row_spec(B, D, lambda b, p, *_: b),
+            _row_spec(C, D, lambda b, p, c_p, *_: c_p[b, p]),
+            _row_spec(C, L, lambda b, p, c_p, *_: c_p[b, p]),
+            pl.BlockSpec((1, L, D), lambda b, p, c_p, *_: (c_p[b, p], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, L), lambda b, p, c_p, m_p: (b, p, 0)),
+        out_specs=pl.BlockSpec((1, P, L), lambda b, p, *_: (b, 0, 0)),
     )
     return pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, P, L), jnp.float32),
         interpret=interpret,
-    )(safe, mask_i, queries, centroids, scale, norm2, q8)
+    )(safe, mask_i, step_scale, queries, centroids, norm2, q8)
 
 
 # --------------------------------------------------------------------------
 # fused in-kernel top-k over int8 residual postings
 # --------------------------------------------------------------------------
-def _qtile_topk_q8_kernel(tc_ref, q_ref, cent_ref, scale_ref, norm2_ref,
+def _qtile_topk_q8_kernel(tc_ref, sc_ref, q_ref, cent_ref, norm2_ref,
                           pids_ref, qsel_ref, q8_ref, od_ref, oi_ref):
+    t = pl.program_id(0)
     s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _init():
-        od_ref[...] = jnp.full(od_ref.shape, jnp.inf, od_ref.dtype)
-        oi_ref[...] = jnp.full(oi_ref.shape, -1, oi_ref.dtype)
-
-    q = q_ref[...].astype(jnp.float32)                  # (bq, D)
-    cent = cent_ref[...].astype(jnp.float32)            # (1, D)
-    r8 = q8_ref[0].astype(jnp.float32)                  # (L, D)
-    sc = scale_ref[0, 0, 0].astype(jnp.float32)         # ()
-    n2 = norm2_ref[...].astype(jnp.float32)             # (1, L)
-    qc = q - cent                                       # (bq, D)
-    cross = jax.lax.dot_general(
-        qc, r8, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )                                                   # (bq, L) — one MXU op
-    d = jnp.sum(qc * qc, axis=1, keepdims=True) - 2.0 * sc * cross + n2
-    d = jnp.maximum(d, 0.0)
-    bq = d.shape[0]
-    sel = jnp.reshape(qsel_ref[...], (bq, 1)) > 0       # (bq, 1)
-    ids = jnp.broadcast_to(pids_ref[...], d.shape).astype(jnp.int32)
-    d = jnp.where(sel & (ids >= 0), d, jnp.inf)
-    cat_d = jnp.concatenate([od_ref[...], d], axis=1)
-    cat_i = jnp.concatenate([oi_ref[...], ids], axis=1)
-    nd, ni = _extract_topk(cat_d, cat_i, od_ref.shape[-1])
-    od_ref[...] = nd
-    oi_ref[...] = ni
+    c = tc_ref[t, s]
+    d = _residual_l2(q_ref[...].astype(jnp.float32),
+                     _pick_row(cent_ref, c).astype(jnp.float32), q8_ref[0],
+                     sc_ref[t, s], _pick_row(norm2_ref, c))
+    _merge_block(d, _pick_row(pids_ref, c), qsel_ref, od_ref, oi_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("k2", "bq", "interpret"))
@@ -143,42 +130,30 @@ def ivf_scan_q8_topk(
     slightly-different residual distances of closure duplicates (each copy is
     quantized against its own centroid)."""
     C, L, D = q8.shape
-    B, P = cids.shape
-    padb = (-B) % bq
-    if padb:
-        queries = jnp.pad(queries, ((0, padb), (0, 0)))
-        cids = jnp.pad(cids, ((0, padb), (0, 0)))
-        mask = jnp.pad(jnp.asarray(mask, bool), ((0, padb), (0, 0)))
-    bp = B + padb
-    nb = bp // bq
-    s_len = bq * P
-    tile_cids, qsel = plan_tile_probes(cids, mask, bq, C)
+    B = cids.shape[0]
+    queries, tile_cids, qsel = tile_plan(cids, mask, queries, bq, C)
+    nb, s_len = tile_cids.shape
+    step_scale = scale.reshape(C).astype(jnp.float32)[tile_cids]  # (nb, S)
+    out_specs, out_shape = topk_outputs(nb, bq, k2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb, s_len),
         in_specs=[
-            pl.BlockSpec((bq, D), lambda t, s, tc: (t, 0)),
-            pl.BlockSpec((1, D), lambda t, s, tc: (tc[t, s], 0)),
-            pl.BlockSpec((1, 1, 1), lambda t, s, tc: (tc[t, s], 0, 0)),
-            pl.BlockSpec((1, L), lambda t, s, tc: (tc[t, s], 0)),
-            pl.BlockSpec((1, L), lambda t, s, tc: (tc[t, s], 0)),
-            pl.BlockSpec((1, 1, bq), lambda t, s, tc: (t, s, 0)),
-            pl.BlockSpec((1, L, D), lambda t, s, tc: (tc[t, s], 0, 0)),
+            pl.BlockSpec((bq, D), lambda t, s, *_: (t, 0)),
+            _row_spec(C, D, lambda t, s, tc, _: tc[t, s]),
+            _row_spec(C, L, lambda t, s, tc, _: tc[t, s]),
+            _row_spec(C, L, lambda t, s, tc, _: tc[t, s]),
+            pl.BlockSpec((1, bq, s_len), lambda t, s, *_: (t, 0, 0)),
+            pl.BlockSpec((1, L, D), lambda t, s, tc, _: (tc[t, s], 0, 0)),
         ],
-        out_specs=[
-            pl.BlockSpec((bq, k2), lambda t, s, tc: (t, 0)),
-            pl.BlockSpec((bq, k2), lambda t, s, tc: (t, 0)),
-        ],
+        out_specs=out_specs,
     )
     od, oi = pl.pallas_call(
         _qtile_topk_q8_kernel,
         grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((bp, k2), jnp.float32),
-            jax.ShapeDtypeStruct((bp, k2), jnp.int32),
-        ),
+        out_shape=out_shape,
         interpret=interpret,
-    )(tile_cids, queries, centroids, scale, norm2,
+    )(tile_cids, step_scale, queries, centroids, norm2,
       posting_ids.astype(jnp.int32), qsel, q8)
     return od[:B], oi[:B]

@@ -26,6 +26,12 @@ a gather/scatter unit.  Padding contract: padded D columns are zeros (exact
 for every distance term), padded K rows are masked to +inf before the argmin
 (so they accumulate nothing), padded N rows are masked out of the one-hot
 (so they perturb no sums) and sliced off the assignment outputs.
+
+The live-row count is a run-time scalar (SMEM scalar prefetch), not a
+compile-time constant: a caller that pads its points to a shape bucket
+passes ``n_valid`` and reuses one compiled program for every point count
+in the bucket.  Rows at or past ``n_valid`` accumulate nothing and report
+min-dist ``-inf``, so a worst-served selection never picks them.
 """
 from __future__ import annotations
 
@@ -34,10 +40,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _kernel(x_ref, c_ref, a_ref, m_ref, s_ref, cnt_ref, *,
-            n_pts: int, n_cents: int, bn: int):
+def _kernel(nv_ref, x_ref, c_ref, a_ref, m_ref, s_ref, cnt_ref, *,
+            n_cents: int, bn: int):
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -59,10 +66,10 @@ def _kernel(x_ref, c_ref, a_ref, m_ref, s_ref, cnt_ref, *,
     d = jnp.where(col < n_cents, d, jnp.inf)            # padded centroids dead
     a = jnp.argmin(d, axis=1).astype(jnp.int32)         # (BN,)
     md = jnp.min(d, axis=1)
-    a_ref[...] = a[:, None]
-    m_ref[...] = md[:, None]
     row = jax.lax.broadcasted_iota(jnp.int32, (bn, 1), 0)[:, 0] + i * bn
-    live = row < n_pts                                  # padded points dead
+    live = row < nv_ref[0]                              # padded points dead
+    a_ref[...] = a[:, None]
+    m_ref[...] = jnp.where(live, md, -jnp.inf)[:, None]
     oh = ((col == a[:, None]) & live[:, None]).astype(jnp.float32)  # (BN, Kp)
     s_ref[...] += jax.lax.dot_general(                  # (Kp, Dp) — MXU M-step
         oh, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
@@ -78,6 +85,7 @@ def _ceil_mult(x: int, m: int) -> int:
 def kmeans_assign_update(
     x: jax.Array,          # (N, D) points
     centroids: jax.Array,  # (K, D)
+    n_valid=None,          # live rows (scalar, traced); None = all N
     *,
     bn: int = 512,
     interpret: bool = False,
@@ -104,21 +112,27 @@ def kmeans_assign_update(
     xp = jnp.pad(x, ((0, (-n) % bn_), (0, dp - d)))
     cp = jnp.pad(centroids, ((0, kp - k), (0, dp - d)))
     n_blocks = xp.shape[0] // bn_
+    nv = jnp.reshape(n if n_valid is None else n_valid, (1,)).astype(
+        jnp.int32)
 
-    a, md, sums, counts = pl.pallas_call(
-        functools.partial(_kernel, n_pts=n, n_cents=k, bn=bn_),
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(n_blocks,),
         in_specs=[
-            pl.BlockSpec((bn_, dp), lambda i: (i, 0)),
-            pl.BlockSpec((kp, dp), lambda i: (0, 0)),
+            pl.BlockSpec((bn_, dp), lambda i, _: (i, 0)),
+            pl.BlockSpec((kp, dp), lambda i, _: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((bn_, 1), lambda i: (i, 0)),
-            pl.BlockSpec((bn_, 1), lambda i: (i, 0)),
+            pl.BlockSpec((bn_, 1), lambda i, _: (i, 0)),
+            pl.BlockSpec((bn_, 1), lambda i, _: (i, 0)),
             # revisited across the whole point grid: VMEM-resident accumulators
-            pl.BlockSpec((kp, dp), lambda i: (0, 0)),
-            pl.BlockSpec((1, kp), lambda i: (0, 0)),
+            pl.BlockSpec((kp, dp), lambda i, _: (0, 0)),
+            pl.BlockSpec((1, kp), lambda i, _: (0, 0)),
         ],
+    )
+    a, md, sums, counts = pl.pallas_call(
+        functools.partial(_kernel, n_cents=k, bn=bn_),
+        grid_spec=grid_spec,
         out_shape=(
             jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.int32),
             jax.ShapeDtypeStruct((xp.shape[0], 1), jnp.float32),
@@ -126,5 +140,5 @@ def kmeans_assign_update(
             jax.ShapeDtypeStruct((1, kp), jnp.float32),
         ),
         interpret=interpret,
-    )(xp, cp)
+    )(nv, xp, cp)
     return a[:n, 0], md[:n, 0], sums[:k, :d], counts[0, :k]

@@ -1,10 +1,17 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On CPU (this container) kernels run with ``interpret=True`` (Pallas executes
-the kernel body with jnp semantics); on TPU they lower to Mosaic.  Callers
-never pass ``interpret`` themselves — ``_interp()`` resolves it per backend.
+On TPU the kernels lower to Mosaic.  On CPU — the test backend — they run
+with ``interpret=True`` (Pallas executes the kernel body with jnp
+semantics).  Any other backend is refused rather than silently interpreted.
+Callers never pass ``interpret`` themselves — ``_interp()`` resolves it.
+
+``FALLBACKS`` counts the calls that a TPU run routed to the jnp oracle
+because the kernel's VMEM estimate exceeded the budget, keyed by kernel
+name, so a run can report that it did not serve through the kernel.
 """
 from __future__ import annotations
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -13,9 +20,16 @@ from . import ivf_scan as _ivf
 from . import pairwise_l2 as _pw
 from . import ref as ref
 
+FALLBACKS: collections.Counter = collections.Counter()
+
 
 def _interp() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"Pallas kernels lower to Mosaic on TPU and are interpreted on "
+            f"CPU; backend {backend!r} is not supported")
+    return backend == "cpu"
 
 
 def pairwise_l2(a, b, *, bn: int = 128, bm: int = 128, bd: int = 512):
@@ -46,10 +60,12 @@ _ASSIGN_VMEM_FLOATS = 1 << 21   # ~8 MiB f32 working set (half of v5e VMEM,
 _ASSIGN_BN = 512                # point-block rows per grid step
 
 
-def kmeans_assign_update_tile(x, centroids):
+def kmeans_assign_update_tile(x, centroids, n_valid=None):
     """Single-tile fused assign+accumulate (jittable; kernel on TPU, jnp
     oracle elsewhere).  Returns (assign, min_dist, sums, counts) — the
     building block of kmeans_assign_update and kmeans_sharded_step.
+    Rows at or past ``n_valid`` (a traced scalar; None = all) are padding:
+    they accumulate nothing and report min-dist -inf.
 
     The kernel's per-step VMEM working set is the whole (Kp, Dp) centroid
     block PLUS the revisited (Kp, Dp) sums accumulator PLUS the (BN, Kp)
@@ -61,14 +77,16 @@ def kmeans_assign_update_tile(x, centroids):
     kp = ((k + 127) // 128) * 128
     dp = ((d + 127) // 128) * 128
     need = 2 * kp * dp + 2 * _ASSIGN_BN * kp + _ASSIGN_BN * dp
-    if jax.default_backend() == "tpu" and need <= _ASSIGN_VMEM_FLOATS:
-        from . import kmeans_assign as _km
-        return _km.kmeans_assign_update(x, centroids, bn=_ASSIGN_BN,
-                                        interpret=False)
-    return _ref_assign_tile(x, centroids)
+    if jax.default_backend() == "tpu":
+        if need <= _ASSIGN_VMEM_FLOATS:
+            from . import kmeans_assign as _km
+            return _km.kmeans_assign_update(x, centroids, n_valid,
+                                            bn=_ASSIGN_BN, interpret=False)
+        FALLBACKS["kmeans_assign_update"] += 1
+    return _ref_assign_tile(x, centroids, n_valid)
 
 
-def kmeans_assign_update(x, centroids, *, chunk: int = 16384):
+def kmeans_assign_update(x, centroids, *, chunk: int = 16384, n_valid=None):
     """Fused Lloyd iteration: E-step argmin + M-step accumulation in one pass.
 
     Returns (assign (N,), min_dist (N,), sums (K, D) f32, counts (K,) i32).
@@ -77,13 +95,16 @@ def kmeans_assign_update(x, centroids, *, chunk: int = 16384):
     host scatter-add both disappear — only (K, D) + (K,) + 2*(N,) cross HBM.
     Per-chunk counts are exact small integers in f32 (chunk <= 2^24); the
     cross-chunk fold is integer, so counts stay exact at any corpus size.
+    ``n_valid`` marks the rows past it as padding (see the tile).
     """
     n = x.shape[0]
     outs_a, outs_m = [], []
     sums = None
     counts = None
     for s in range(0, n, chunk):
-        a, md, ps, pc = kmeans_assign_update_tile(x[s:s + chunk], centroids)
+        nv = None if n_valid is None else jnp.clip(n_valid - s, 0, chunk)
+        a, md, ps, pc = kmeans_assign_update_tile(x[s:s + chunk], centroids,
+                                                  nv)
         pc = jnp.round(pc).astype(jnp.int32)
         outs_a.append(a)
         outs_m.append(md)
@@ -105,9 +126,12 @@ def kmeans_mstep(sums, counts, reseed):
     kp = ((k + 127) // 128) * 128
     dp = ((d + 127) // 128) * 128
     need = 3 * kp * dp + 2 * kp * kp
-    if jax.default_backend() == "tpu" and need <= _ASSIGN_VMEM_FLOATS:
-        from . import kmeans_mstep as _km_mstep
-        return _km_mstep.kmeans_mstep(sums, counts, reseed, interpret=False)
+    if jax.default_backend() == "tpu":
+        if need <= _ASSIGN_VMEM_FLOATS:
+            from . import kmeans_mstep as _km_mstep
+            return _km_mstep.kmeans_mstep(sums, counts, reseed,
+                                          interpret=False)
+        FALLBACKS["kmeans_mstep"] += 1
     return _ref_mstep_tile(sums, counts, reseed)
 
 
@@ -122,8 +146,8 @@ def _ref_tile(a, b):
 
 
 @jax.jit
-def _ref_assign_tile(x, centroids):
-    return ref.kmeans_assign_update_ref(x, centroids)
+def _ref_assign_tile(x, centroids, n_valid=None):
+    return ref.kmeans_assign_update_ref(x, centroids, n_valid)
 
 
 def ivf_scan(postings, cids, mask, queries):

@@ -31,6 +31,7 @@ def assign_distances_f64(x, centroids, assign):
 def kmeans_assign_update_ref(
     x: jax.Array,          # (N, D)
     centroids: jax.Array,  # (K, D)
+    n_valid=None,          # live rows; rows past it are dead (see kernel)
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Oracle for the fused assign-and-accumulate kernel.
 
@@ -44,6 +45,10 @@ def kmeans_assign_update_ref(
     a = jnp.argmin(d, axis=1).astype(jnp.int32)
     md = jnp.min(d, axis=1)
     oh = jax.nn.one_hot(a, centroids.shape[0], dtype=jnp.float32)
+    if n_valid is not None:
+        live = jnp.arange(x.shape[0]) < n_valid
+        md = jnp.where(live, md, -jnp.inf)
+        oh = oh * live[:, None]
     sums = jax.lax.dot_general(                          # (K, D)
         oh, x.astype(jnp.float32), (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,

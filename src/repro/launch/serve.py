@@ -49,6 +49,7 @@ from repro.distributed import (
     ShardedFabric,
     plan_failover,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.lifecycle import VersionManager
 from repro.obs import (
     HarvestRing,
@@ -86,6 +87,17 @@ class Deployment:
     pipeline: PrefetchPipeline
     queries: np.ndarray          # probe pool for recall spot checks
     true10: np.ndarray
+    report: object = None        # BuildReport (stage seconds, clusters)
+
+
+def build_config(nprobe_max: int = 16) -> BuildConfig:
+    """The node's index build: SPANN-sized posting lists (<= 96 primaries
+    in L=128 slots) and a two-level LLSP pruner whose top level is the
+    serving ``nprobe_max``."""
+    levels = (max(nprobe_max // 2, 1), nprobe_max)
+    return BuildConfig(max_cluster_size=96, cluster_len=128,
+                       coarse_per_task=5000, n_workers=2,
+                       llsp=LLSPConfig(levels=levels, n_ratio_features=8))
 
 
 def deploy(arena: ChunkArena, name: str, spec, workdir: str,
@@ -95,11 +107,8 @@ def deploy(arena: ChunkArena, name: str, spec, workdir: str,
     x = make_vectors(spec)
     q, topk = make_queries(spec, 256)
     topk = np.minimum(topk, 50).astype(np.int32)
-    cfg = BuildConfig(max_cluster_size=96, cluster_len=128,
-                      coarse_per_task=5000, n_workers=2,
-                      llsp=LLSPConfig(levels=(8, 16), n_ratio_features=8))
-    index, llsp, report = build_index(x, cfg, workdir, queries=q,
-                                      query_topk=topk)
+    index, llsp, report = build_index(x, build_config(scfg.nprobe_max),
+                                      workdir, queries=q, query_topk=topk)
     cluster_bytes = index.cluster_len * index.dim * 4
     extents = arena.allocate_index(name, index.n_clusters, cluster_bytes)
     striping = plan_striping(index.n_clusters, n_shards, extents)
@@ -111,20 +120,24 @@ def deploy(arena: ChunkArena, name: str, spec, workdir: str,
                      dtype="int8" if tier == "q8" else "float32",
                      extents=extents)
     meta.save(os.path.join(workdir, f"{name}.meta.json"))
+    # streamed-row quantum: a batch's union grows with nprobe, and so would
+    # the count of row buckets the warmup compiles at a fixed quantum
+    row_bucket = max(256, 4 * scfg.nprobe_max)
     if tier == "q8":
         # quantized serving default: q8 hot tier + mmap flash tier (f32
         # corpus, arena-accounted) + adaptive f32 re-rank at harvest
         pipeline = make_quantized_pipeline(
             index, llsp, scfg, arena=arena, name=name, vectors=x,
             flash_path=os.path.join(workdir, f"{name}.flash.f32"),
-            rerank=rerank, with_flash=with_rerank)
+            rerank=rerank, with_flash=with_rerank, row_bucket=row_bucket)
     else:
         hot_tier = TieredPostings(np.asarray(index.postings),
                                   np.asarray(index.posting_ids))
         # dup_bound auto-derives from the build's realized replication, so a
         # rebuilt index with a different max_replicas can never outrun the
         # oracle's pre-selection (the ROADMAP dup_bound=8 hazard)
-        pipeline = PrefetchPipeline(index, llsp, scfg, tier=hot_tier)
+        pipeline = PrefetchPipeline(index, llsp, scfg, tier=hot_tier,
+                                    row_bucket=row_bucket)
     _, t10 = brute_force_topk(jnp.asarray(x), jnp.asarray(q), 10)
     hot_note = ""
     if tier == "q8":
@@ -142,7 +155,7 @@ def deploy(arena: ChunkArena, name: str, spec, workdir: str,
           f"dup_bound {pipeline.dup_bound}, tier={pipeline.tier_kind}"
           + hot_note)
     return Deployment(name, index, llsp, spec, meta, striping, rmap,
-                      pipeline, q, np.asarray(t10))
+                      pipeline, q, np.asarray(t10), report)
 
 
 def undeploy(arena: ChunkArena, dep: Deployment) -> None:
@@ -180,6 +193,17 @@ def probe_recall(engine: ServeEngine, dep: Deployment,
     rows = [want[r] for r in got]
     ids = np.stack([got[r] for r in got])
     return recall_at_k(ids[:, :10], dep.true10[rows])
+
+
+def fail_on_errors(engine: ServeEngine) -> None:
+    """Exit non-zero when any request completed "failed".  Only a
+    serving-path error completes a request that way (reason ``*_error`` or
+    ``crash_drain``), so a run that printed its counts must not also report
+    success."""
+    if engine.stats.failed:
+        raise SystemExit(
+            f"[serve] {engine.stats.failed} request(s) failed on a "
+            f"serving-path error; newest traceback:\n{engine.last_error}")
 
 
 def make_obs(args) -> Observability:
@@ -285,7 +309,7 @@ def run_fabric(args) -> None:
     deadline_s = args.deadline_ms * 1e-3 or None
     name = list(PAPER_DATASETS)[0]
     with tempfile.TemporaryDirectory() as root:
-        spec = dataclasses.replace(PAPER_DATASETS[name], n=args.n, dim=32)
+        spec = dataclasses.replace(PAPER_DATASETS[name], n=args.n)
         dep = deploy(arena, name, spec, os.path.join(root, name),
                      args.shards, scfg, tier="f32")
         inj = None
@@ -371,6 +395,7 @@ def run_fabric(args) -> None:
         finish_obs(obs, args)
         undeploy(arena, dep)
         arena.validate()
+    fail_on_errors(engine)
 
 
 FABRIC_RUNBOOK = """\
@@ -619,6 +644,7 @@ def main() -> None:
     args = ap.parse_args()
     if args.health_out and args.health_every <= 0:
         args.health_every = 1.0
+    enable_compile_cache()
 
     if args.shards > 0:
         if args.rebuild:
@@ -645,7 +671,7 @@ def main() -> None:
     tiers_seen: list = []          # every deployed tier, incl. swapped-out
     with tempfile.TemporaryDirectory() as root:
         for name in names:
-            spec = dataclasses.replace(PAPER_DATASETS[name], n=args.n, dim=32)
+            spec = dataclasses.replace(PAPER_DATASETS[name], n=args.n)
             deps[name] = deploy(arena, name, spec,
                                 os.path.join(root, name), n_shards, scfg,
                                 tier=args.tier, rerank=rerank,
@@ -819,6 +845,7 @@ def main() -> None:
         for dep in deps.values():
             undeploy(arena, dep)
         arena.validate()
+    fail_on_errors(engine)
 
 
 if __name__ == "__main__":

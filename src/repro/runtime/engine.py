@@ -28,6 +28,7 @@ import collections
 import dataclasses
 import threading
 import time
+import traceback
 from typing import Optional
 
 import numpy as np
@@ -266,6 +267,9 @@ class ServeEngine:
         # call per harvested batch from the completion funnel — recall-proxy
         # streams, shadow audits, and the per-query telemetry harvest
         self.quality = quality
+        # traceback of the newest serving-path error behind a "failed"
+        # completion (empty = none): the CQ carries only the reason label
+        self.last_error = ""
         self._req_ids = iter(range(1 << 62))
         self._swap_lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
@@ -584,7 +588,10 @@ class ServeEngine:
         """Complete a formed batch as "failed" — the serving path errored,
         but every client gets a CQ entry (no abandoned requests, the
         shutdown/crash-drain invariant).  ``reason`` names the stage that
-        errored ("plan_error", "prefetch_error", …)."""
+        errored ("plan_error", "prefetch_error", …).  Every caller runs
+        inside the ``except`` that caught the error, so its traceback is
+        kept in ``last_error``."""
+        self.last_error = traceback.format_exc()
         comps = [Completion(
             req_id=r.req_id, index=r.index, status="failed",
             ids=None, dists=None, nprobe=0,

@@ -650,47 +650,61 @@ class PrefetchPipeline:
         live engine can hit, so traffic never pays a compile.  A cold
         compile (~0.5-1 s) landing mid-trace queues hundreds of arrivals
         past their deadline and the admission controller sheds them — the
-        warmup turns that cliff into a one-time startup cost.  Returns the
-        number of programs compiled."""
+        warmup turns that cliff into a one-time startup cost.  A batch of
+        ``bp`` queries streams at most ``min(C, bp * nprobe_max)`` clusters
+        plus the sentinel row, so larger row buckets are never warmed.
+        Returns the number of programs compiled."""
         if not self.streamed:
             for b in batch_sizes:
                 bp = -(-b // self.pad_batch) * self.pad_batch
                 self.serve_batch(np.zeros((bp, self.index.dim), np.float32),
                                  10)
             return len(batch_sizes) + self._warm_fresh(batch_sizes)
-        quant = getattr(self.tier, "quantized", False)
-        payload = self.tier.q8 if quant else self.tier.postings
-        c, l, d = payload.shape
+        c = self._payload.shape[0]
         max_rows = max_rows or c + 1
         max_rows = -(-max_rows // self.row_bucket) * self.row_bucket
         n = 0
         for b in batch_sizes:
             bp = -(-b // self.pad_batch) * self.pad_batch
-            q = np.zeros((bp, d), np.float32)
-            qd = jnp.asarray(q)
-            _plan_jit(self.index.centroids, self.llsp_params, qd,
+            _plan_jit(self.index.centroids, self.llsp_params,
+                      jnp.zeros((bp, self.index.dim), jnp.float32),
                       jnp.full((bp,), 10, jnp.int32), self.cfg)
-            p = min(self.cfg.nprobe_max, c)
-            for rows in range(self.row_bucket, max_rows + 1, self.row_bucket):
-                if quant:
-                    _scan_streamed_q8_jit(
-                        jnp.zeros((rows, l, d), jnp.int8),
-                        jnp.ones((rows, 1, 1), jnp.float32),
-                        jnp.zeros((rows, l), jnp.float32),
-                        jnp.zeros((rows, d), jnp.float32),
-                        jnp.full((rows, l), -1, jnp.int32),
-                        jnp.zeros((bp, p), jnp.int32),
-                        jnp.zeros((bp, p), bool), qd, self._scan_cfg,
-                        dup_bound=self.dup_bound)
-                else:
-                    _scan_streamed_jit(
-                        jnp.zeros((rows, l, d), jnp.float32),
-                        jnp.full((rows, l), -1, jnp.int32),
-                        jnp.zeros((bp, p), jnp.int32),
-                        jnp.zeros((bp, p), bool), qd, self._scan_cfg,
-                        dup_bound=self.dup_bound)
+            top = min(max_rows, min(c, bp * self.cfg.nprobe_max) + 1)
+            for rows in range(self.row_bucket, top + self.row_bucket,
+                              self.row_bucket):
+                fn, shapes, kw = self._scan_program(bp, rows)
+                fn(*[jnp.zeros(a.shape, a.dtype) for a in shapes], **kw)
                 n += 1
         return n + self._warm_fresh(batch_sizes)
+
+    @property
+    def _payload(self):
+        """The streamed tier's (C, L, D) first-pass posting payload."""
+        return self.tier.q8 if self.quantized else self.tier.postings
+
+    def _scan_program(self, bp: int, rows: int):
+        """``(jit, argument shapes, static kwargs)`` of the streamed scan
+        for a padded batch of ``bp`` queries over ``rows`` packed rows."""
+        c, l, d = self._payload.shape
+        p = min(self.cfg.nprobe_max, c)
+        sds = jax.ShapeDtypeStruct
+        tail = (sds((bp, p), jnp.int32), sds((bp, p), jnp.bool_),
+                sds((bp, d), jnp.float32))
+        kw = dict(cfg=self._scan_cfg, dup_bound=self.dup_bound)
+        if self.quantized:
+            return _scan_streamed_q8_jit, (
+                sds((rows, l, d), jnp.int8), sds((rows, 1, 1), jnp.float32),
+                sds((rows, l), jnp.float32), sds((rows, d), jnp.float32),
+                sds((rows, l), jnp.int32)) + tail, kw
+        return _scan_streamed_jit, (
+            sds((rows, l, d), jnp.float32), sds((rows, l), jnp.int32)) + tail, kw
+
+    def lower_scan(self, bp: int, rows: int):
+        """The streamed scan program that serves a padded batch of ``bp``
+        queries over ``rows`` packed rows, lowered: its text shows whether
+        the scan runs as a Mosaic kernel (``tpu_custom_call``)."""
+        fn, shapes, kw = self._scan_program(bp, rows)
+        return fn.lower(*shapes, **kw)
 
     def _warm_fresh(self, batch_sizes) -> int:
         """Pre-compile the freshness-merge program per padded batch size

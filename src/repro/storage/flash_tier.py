@@ -106,10 +106,13 @@ class FlashTier:
         self._arena = arena
         self.extents: List[Extent] = []
         if arena is not None:
-            n_ext = -(-self.n // ROWS_PER_EXTENT)
+            # wide rows (128-d and up) get shorter extents, so one extent
+            # never outgrows an arena chunk
+            rows = max(min(ROWS_PER_EXTENT,
+                           arena.chunk_bytes // self.row_bytes), 1)
             self.extents = arena.allocate_index(
-                f"{self.name}-e{self.epoch}", n_ext,
-                ROWS_PER_EXTENT * self.row_bytes)
+                f"{self.name}-e{self.epoch}", -(-self.n // rows),
+                rows * self.row_bytes)
 
     @property
     def row_bytes(self) -> int:

@@ -85,6 +85,53 @@ def test_kmeans_assign_update_sweep(n, d, k, bn, dtype):
                                rtol=tol * 10, atol=tol * 100)
 
 
+@pytest.mark.parametrize("impl", ["kernel", "ref", "ops"])
+def test_kmeans_assign_update_n_valid_masks_padding(impl):
+    """Rows at or past ``n_valid`` are padding: the live rows get the
+    unpadded call's result, the padding adds nothing to sums or counts and
+    reports min-dist -inf (so a worst-served pick never takes it)."""
+    from repro.kernels.kmeans_assign import kmeans_assign_update
+
+    n, pad, d, k = 300, 212, 24, 7
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    x = jax.random.normal(k1, (n, d))
+    c = jax.random.normal(k2, (k, d))
+    xp = jnp.pad(x, ((0, pad), (0, 0)), constant_values=3.0)
+    run = {"kernel": lambda a, nv=None: kmeans_assign_update(
+               a, c, nv, bn=64, interpret=True),
+           "ref": lambda a, nv=None: ref.kmeans_assign_update_ref(a, c, nv),
+           "ops": lambda a, nv=None: ops.kmeans_assign_update(
+               a, c, chunk=128, n_valid=nv)}[impl]
+    a0, m0, s0, c0 = run(x)
+    a1, m1, s1, c1 = run(xp, jnp.int32(n))
+    np.testing.assert_array_equal(np.asarray(a1)[:n], np.asarray(a0))
+    np.testing.assert_allclose(np.asarray(m1)[:n], np.asarray(m0),
+                               rtol=1e-6, atol=1e-6)
+    assert np.all(np.isneginf(np.asarray(m1)[n:]))
+    np.testing.assert_array_equal(np.asarray(c1), np.asarray(c0))
+    np.testing.assert_allclose(np.asarray(s1), np.asarray(s0),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_kmeans_bucketed_rows_compile_once_per_bucket():
+    """The device Lloyd loop pads points to a power-of-two bucket, so
+    k-means at two point counts in one bucket reuses the compiled
+    assign program (the build's recursive splitter calls it at a
+    different count for every node)."""
+    from repro.build.kmeans import kmeans
+
+    rng = np.random.default_rng(3)
+    x = rng.normal(size=(700, 8)).astype(np.float32)
+    kmeans(x[:600], 4, iters=2, fused=True)
+    before = ops._ref_assign_tile._cache_size()
+    cents, assign, inertia = kmeans(x[:650], 4, iters=2, fused=True)
+    assert ops._ref_assign_tile._cache_size() == before
+    assert assign.shape == (650,) and assign.max() < 4
+    # inertia is measured before the last M-step, which cannot raise it
+    after = ((x[:650] - cents[assign]) ** 2).sum()
+    assert 0 < after <= inertia * (1 + 1e-5)
+
+
 def test_kmeans_assign_update_accumulates_across_blocks():
     """Multi-block grids must fold partial sums into the SAME revisited
     VMEM block — catch any init/flush bug by making every block contribute

@@ -270,6 +270,32 @@ def test_failed_paths_stamp_stage_reason(stage, reason):
     assert eng.obs.metrics.counter("engine.not_ok").value(reason) == 3
 
 
+@pytest.mark.parametrize("stage", ["", "plan", "prefetch", "dispatch",
+                                   "harvest"])
+def test_serve_exits_nonzero_on_failed_requests(stage):
+    """launch/serve.py's end-of-run check: a failed completion (every one
+    carries a *_error reason) turns into a non-zero exit that shows the
+    error; a clean run passes."""
+    from repro.launch.serve import fail_on_errors
+
+    eng = _stub_engine(stage, clock=time.monotonic)
+    eng.start()
+    try:
+        for _ in range(3):
+            eng.submit(np.zeros(4, np.float32), CFG.k, index="s", block=True)
+        assert eng.qp.wait_completions(3, timeout=10.0)
+    finally:
+        eng.stop(drain=True)
+    if not stage:
+        fail_on_errors(eng)
+        return
+    with pytest.raises(SystemExit) as ei:
+        fail_on_errors(eng)
+    assert ei.value.code != 0
+    assert "3 request(s) failed" in str(ei.value.code)
+    assert "RuntimeError: boom" in str(ei.value.code)
+
+
 def test_shed_paths_stamp_deadline_and_drain_reasons():
     vt = [0.0]
     eng = _stub_engine("", clock=lambda: vt[0])

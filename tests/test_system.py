@@ -102,3 +102,29 @@ def test_shard_failure_failover(system):
     assert not set(hot.tolist()) & set(plan.lost.tolist())
     # coverage loss is bounded by the failed shard's cold share
     assert plan.n_lost <= C // n_shards + 1
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_dir(monkeypatch, tmp_path, from_env):
+    """The persistent compile cache sits at a fixed path: the operator's
+    JAX_COMPILATION_CACHE_DIR when set (left to JAX, nothing else set),
+    otherwise .jax_cache/ at the root of the checkout."""
+    import os
+
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            want = os.path.join(root, ".jax_cache")
+            assert enable_compile_cache() == want
+            assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
