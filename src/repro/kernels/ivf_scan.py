@@ -71,6 +71,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# the served scan's op name in the compiled program and the device trace
+TOPK_KERNEL_NAME = "ivf_scan_topk"
+
 
 # --------------------------------------------------------------------------
 # shared in-kernel helpers
@@ -399,5 +402,6 @@ def ivf_scan_topk(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=TOPK_KERNEL_NAME,
     )(tile_cids, queries, posting_ids.astype(jnp.int32), qsel, postings)
     return od[:B], oi[:B]

@@ -35,6 +35,9 @@ from .ivf_scan import (
     _dot_t, _merge_block, _pick_row, _row_spec, tile_plan, topk_outputs,
 )
 
+# the served scan's op name in the compiled program and the device trace
+TOPK_KERNEL_NAME = "ivf_scan_q8_topk"
+
 
 def _residual_l2(q, cent, r8, scale, n2):
     """||q - (c + s r8)||^2 = ||q - c||^2 - 2 s (q - c).r8 + s^2 ||r8||^2
@@ -154,6 +157,7 @@ def ivf_scan_q8_topk(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name=TOPK_KERNEL_NAME,
     )(tile_cids, step_scale, queries, centroids, norm2,
       posting_ids.astype(jnp.int32), qsel, q8)
     return od[:B], oi[:B]

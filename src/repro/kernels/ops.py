@@ -17,10 +17,15 @@ import jax
 import jax.numpy as jnp
 
 from . import ivf_scan as _ivf
+from . import ivf_scan_q8 as _q8
 from . import pairwise_l2 as _pw
 from . import ref as ref
 
 FALLBACKS: collections.Counter = collections.Counter()
+
+# op names of the served scan kernels, pinned on their pallas_call: device
+# trace readers find the kernels by these
+SCAN_KERNEL_NAMES = (_ivf.TOPK_KERNEL_NAME, _q8.TOPK_KERNEL_NAME)
 
 
 def _interp() -> bool:
@@ -164,7 +169,6 @@ def ivf_scan_clustermajor(postings, active, qsel, queries):
 
 def ivf_scan_q8(q8, scale, norm2, centroids, cids, mask, queries):
     """Fused int8-residual posting scan (hillclimb it.3 hot path)."""
-    from . import ivf_scan_q8 as _q8
     return _q8.ivf_scan_q8(q8, scale, norm2, centroids, cids, mask, queries,
                            interpret=_interp())
 
@@ -181,7 +185,6 @@ def ivf_scan_topk(postings, posting_ids, cids, mask, queries, *, k2, bq=8):
 def ivf_scan_q8_topk(q8, scale, norm2, centroids, posting_ids, cids, mask,
                      queries, *, k2, bq=8):
     """Candidate-compressed int8-residual scan (see ivf_scan_topk)."""
-    from . import ivf_scan_q8 as _q8
     return _q8.ivf_scan_q8_topk(q8, scale, norm2, centroids, posting_ids,
                                 cids, mask, queries, k2=k2, bq=bq,
                                 interpret=_interp())

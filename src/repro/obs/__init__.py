@@ -15,9 +15,16 @@ Components take ``obs=None`` and default to a PRIVATE disabled bundle
 (``Observability.off()``) — no module-global registry, so parallel tests
 and paired A/B trials never share state.  A disabled bundle keeps metrics
 live (they are O(1) and replace the old grow-forever lists) but turns the
-trace recorder into one-integer-compare no-ops; the tracing-overhead gate
-in ``benchmarks/bench_serving_pipeline.py`` measures exactly this
-off-vs-``sample_rate=1.0`` pair.
+trace recorder into one-integer-compare no-ops; the CPU smoke of
+``benchmarks/bench_serving_pipeline.py`` gates this off-vs-
+``sample_rate=1.0`` pair on CPU only.
+
+Separately, and with no bundle, the serving path opens profiler spans at
+its work sites (:func:`repro.obs.trace.region`, named in
+``repro.obs.trace.SERVE_SPANS``): they land in a JAX profile beside the
+device's ops and cost one activity check when no profile runs.  The on-chip
+benchmark (``bench/run.py``) serves with the recorder off; its traced runs
+hold these spans.
 """
 from __future__ import annotations
 

@@ -44,6 +44,15 @@ Event model -> ``trace_event`` mapping (https://perfetto.dev):
 Tracks are logical (``"requests"``, ``"shard-3"``, ``"batch-5"``, …) and
 mapped to synthetic tids with thread_name metadata at export, so the
 flamegraph reads by subsystem rather than by python thread id.
+
+Profiler spans.  The recorder above keeps its own clock; to lay host work
+beside the device's ops, the serving path also opens :func:`region` spans
+(``jax.profiler.TraceAnnotation``) where the work runs, on the JAX
+profiler's clock.  :data:`SERVE_SPANS` names every such span and the thread
+it runs on; each carries a ``batch`` arg equal to ``StageTimes.seq``.  The
+serving threads give the OS their names (:func:`name_os_thread`), so each
+thread's line in the profile is labelled by it.  Outside a profile a region
+costs one native activity check and records nothing.
 """
 from __future__ import annotations
 
@@ -51,6 +60,78 @@ import json
 import threading
 import time
 from typing import Optional
+
+from jax.profiler import TraceAnnotation
+
+# -- profiler spans of the serving path ------------------------------------
+POLLER_THREAD = "serve-poller"     # ServeEngine's poller
+GATHER_THREAD = "prefetch"         # PrefetchPipeline's gather worker (prefix)
+RERANK_THREAD = "rerank"           # PrefetchPipeline's flash-read worker
+
+SPAN_IDLE = "serve.idle"           # poller asleep: nothing prepared or in flight
+SPAN_FORM = "serve.form"           # batcher.form + epoch routing
+SPAN_PLAN = "serve.plan"           # PrefetchPipeline.plan
+SPAN_WAIT_GATHER = "serve.wait_gather"  # dispatch joins the gather worker
+SPAN_DISPATCH = "serve.dispatch"   # the jitted scan call (enqueue only)
+SPAN_HARVEST = "serve.harvest"     # blocking readback of the scan outputs
+SPAN_RERANK = "serve.rerank"       # flash-tier f32 re-rank
+SPAN_COMPLETE = "serve.complete"   # CQ entries, counters, batcher EWMA
+SPAN_GATHER = "tier.gather"        # host union gather (np.take)
+SPAN_H2D = "tier.h2d"              # packed tensors handed to the device
+SPAN_FLASH_READ = "flash.read"     # one flash-tier read burst
+
+# span name -> the thread it runs on (a worker pool's name prefix)
+SERVE_SPANS = {
+    SPAN_IDLE: POLLER_THREAD, SPAN_FORM: POLLER_THREAD,
+    SPAN_PLAN: POLLER_THREAD, SPAN_WAIT_GATHER: POLLER_THREAD,
+    SPAN_DISPATCH: POLLER_THREAD, SPAN_HARVEST: POLLER_THREAD,
+    SPAN_RERANK: POLLER_THREAD, SPAN_COMPLETE: POLLER_THREAD,
+    SPAN_GATHER: GATHER_THREAD, SPAN_H2D: GATHER_THREAD,
+    SPAN_FLASH_READ: RERANK_THREAD,
+}
+
+_profiling = TraceAnnotation.is_enabled
+
+
+class _NoSpan:
+    """What :func:`region` returns outside a profile: enters, exits and
+    takes metadata, recording nothing."""
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    def set_metadata(self, **args) -> None:
+        return None
+
+
+_NO_SPAN = _NoSpan()
+
+
+def region(name: str, **args):
+    """Context manager: a profiler span ``name`` with ``args`` on the
+    calling thread, or a shared no-op when no profile is running.  Args
+    known only inside the span go in through ``set_metadata``."""
+    if not _profiling():
+        return _NO_SPAN
+    return TraceAnnotation(name, **args)
+
+
+def name_os_thread() -> None:
+    """Give the calling thread's Python name to the OS (its first 15 bytes,
+    the Linux limit), which the profiler reads to label the thread's line.
+    Pass as a worker pool's ``initializer``.  Without procfs the thread
+    keeps the name the OS gave it."""
+    path = f"/proc/self/task/{threading.get_native_id()}/comm"
+    try:
+        with open(path, "w") as f:
+            f.write(threading.current_thread().name[:15])
+    except OSError:
+        pass
+
 
 _KNUTH = 2654435761          # Knuth's multiplicative hash constant
 _MASK32 = 0xFFFFFFFF

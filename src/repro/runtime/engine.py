@@ -34,6 +34,10 @@ from typing import Optional
 import numpy as np
 
 from repro.obs import Observability
+from repro.obs.trace import (
+    POLLER_THREAD, SPAN_COMPLETE, SPAN_FORM, SPAN_IDLE, SPAN_PLAN,
+    name_os_thread, region,
+)
 from repro.runtime.pipeline import stage_spans
 
 
@@ -271,6 +275,7 @@ class ServeEngine:
         # completion (empty = none): the CQ carries only the reason label
         self.last_error = ""
         self._req_ids = iter(range(1 << 62))
+        self._batch_ids = iter(range(1 << 62))   # StageTimes.seq, at formation
         self._swap_lock = threading.Lock()
         self._thread: Optional[threading.Thread] = None
         self._stop = threading.Event()
@@ -439,6 +444,10 @@ class ServeEngine:
         self._g_pending.set(self.batcher.pending())
 
     def _complete_batch(self, mb, result, done: float, epoch=None) -> None:
+        with region(SPAN_COMPLETE, batch=result.times.seq):
+            self._finish_batch(mb, result, done, epoch)
+
+    def _finish_batch(self, mb, result, done: float, epoch) -> None:
         comps = []
         partial = getattr(result, "partial", None)
         partial_reason = getattr(result, "partial_reason", "no_replica")
@@ -524,16 +533,20 @@ class ServeEngine:
         Epoch routing happens HERE: the batch takes an in-flight reference
         on the current epoch and carries it to harvest, so a concurrent
         swap cannot re-route (or early-retire) a batch mid-flight."""
-        mb, sheds = self.batcher.form(now, force=force)
+        with region(SPAN_FORM) as span:
+            mb, sheds = self.batcher.form(now, force=force)
+            epoch = None
+            if mb is not None:
+                seq = next(self._batch_ids)
+                span.set_metadata(batch=seq)
+                if self.versions is not None:
+                    epoch = self.versions.route(mb.index)
         if sheds:
             self.stats.shed += len(sheds)
             self.stats.completed += len(sheds)
             self._complete(sheds)
         if mb is None:
             return None
-        epoch = None
-        if self.versions is not None:
-            epoch = self.versions.route(mb.index)
         pipe = epoch.pipeline if epoch is not None else self._pipeline(mb.index)
         queries = np.stack([r.query for r in mb.requests])
         topk = np.asarray([r.topk for r in mb.requests], np.int32)
@@ -552,13 +565,19 @@ class ServeEngine:
             dls = [r.deadline for r in mb.requests if r.deadline is not None]
             kwargs["deadline"] = min(dls) if dls else None
         try:
-            plan = pipe.plan(queries, topk, nprobe_cap=mb.nprobe_cap,
-                             routed=routed, **kwargs)
+            with region(SPAN_PLAN, batch=seq):
+                plan = pipe.plan(queries, topk, nprobe_cap=mb.nprobe_cap,
+                                 routed=routed, **kwargs)
         except Exception:
             # the batch is already formed — its requests MUST complete
             # (failed), never be abandoned with clients blocked on the CQ
             self._fail_batch(mb, now, epoch=epoch, reason="plan_error")
             return None
+        t = getattr(plan, "times", None)
+        if t is not None:
+            t.seq = seq
+            t.formed_at = mb.formed_at
+            t.queue_wait_s = float(mb.waits.sum())
         if self.obs.tracing:
             # sampled request identities ride the plan into the fabric so
             # every shard task (incl. requeue/hedge) tags its queries
@@ -669,6 +688,7 @@ class ServeEngine:
         so with depth >= 2 a short scan finishing early never idles the
         device while the next gather is still on the host.
         """
+        name_os_thread()
         prep = None                    # (mb, pipe, prefetch-handle, epoch)
         inflight = collections.deque() # (mb, pipe, scan-handle, epoch)
         try:
@@ -687,8 +707,9 @@ class ServeEngine:
                     if inflight:
                         self._harvest_head(inflight)
                         continue
-                    self.qp.wait_submissions(
-                        timeout=self.batcher.policy.max_wait_s)
+                    with region(SPAN_IDLE):
+                        self.qp.wait_submissions(
+                            timeout=self.batcher.policy.max_wait_s)
                     continue
                 if len(inflight) >= self.depth:
                     self._harvest_head(inflight)
@@ -749,7 +770,7 @@ class ServeEngine:
         self._stop.clear()
         self._drain_on_stop = True
         self._thread = threading.Thread(
-            target=self._serve_loop, name="serve-poller", daemon=True)
+            target=self._serve_loop, name=POLLER_THREAD, daemon=True)
         self._thread.start()
 
     def stop(self, drain: bool = True) -> None:

@@ -47,6 +47,10 @@ from repro.core.distance import (
 )
 from repro.core.search import SearchConfig, _auto_ncand, _scan_and_rank, decide_nprobe
 from repro.kernels import ops as kops
+from repro.obs.trace import (
+    GATHER_THREAD, RERANK_THREAD, SPAN_DISPATCH, SPAN_HARVEST, SPAN_RERANK,
+    SPAN_WAIT_GATHER, name_os_thread, region,
+)
 from repro.storage.host_tier import QuantizedTieredPostings, TieredPostings
 from repro.storage.flash_tier import FlashTier
 
@@ -79,6 +83,12 @@ class StageTimes:
                                       # candidate list was exhausted
     rerank_round_size: int = 0     # round width this batch actually used
                                    # (== config unless auto_round adapted it)
+    # admission (set by ServeEngine at formation; -1/0 outside an engine)
+    seq: int = -1                  # batch id: the ``batch`` arg of its
+                                   # profiler spans (repro.obs.trace)
+    formed_at: float = 0.0         # batcher release time (engine clock)
+    queue_wait_s: float = 0.0      # its requests' summed wait from submit
+                                   # to formation (MicroBatch.waits)
 
     @property
     def total(self) -> float:
@@ -332,13 +342,15 @@ class PrefetchPipeline:
             dup_bound = max_id_replicas(pids)
         self.dup_bound = max(int(dup_bound), 1)
         self._gatherer = ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="prefetch")
+            max_workers=1, thread_name_prefix=GATHER_THREAD,
+            initializer=name_os_thread)
         # rerank reads get their own single-lane SQ (same DMA-engine idiom
         # as the prefetch gatherer): sharing the gatherer would queue batch
         # i's rerank I/O behind batch i+1's union gather and serialize the
         # two stages the overlap argument needs concurrent.
         self._reranker = (ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="rerank")
+            max_workers=1, thread_name_prefix=RERANK_THREAD,
+            initializer=name_os_thread)
             if flash is not None else None)
         # per-query recall proxy (quality observability): the overlap of the
         # pre-rerank approximate top-k with the post-rerank exact top-k,
@@ -450,7 +462,8 @@ class PrefetchPipeline:
 
     def _gather(self, plan: _Plan):
         fetched = self.tier.fetch(
-            plan.cids, plan.pmask, bucket=self.row_bucket)
+            plan.cids, plan.pmask, bucket=self.row_bucket,
+            seq=plan.times.seq)
         ev = self.tier.stats.events[-1]    # same thread as the fetch: safe
         plan.times.gather_start = ev.gather_start
         plan.times.gather_end = ev.gather_end
@@ -476,35 +489,13 @@ class PrefetchPipeline:
         concurrent updater had published when the scan launched."""
         plan = prep.plan
         t = plan.times
+        fetched = None
         if self.streamed:
-            fetched = prep.fut.result()
-            t.scan_dispatch = time.perf_counter()
-            if getattr(self.tier, "quantized", False):
-                if reference:
-                    raise ValueError(
-                        "reference scan is an f32-tier A/B baseline; the "
-                        "quantized tier has no pre-runtime twin")
-                q8, scale, norm2, cents, pids, remap = fetched
-                od, oi = _scan_streamed_q8_jit(
-                    q8, scale, norm2, cents, pids, remap,
-                    jnp.asarray(plan.pmask), plan.queries_dev,
-                    self._scan_cfg, dup_bound=self.dup_bound)
-            elif reference:
-                packed, pids, remap = fetched
-                od, oi = _scan_reference_jit(
-                    packed, pids, remap, jnp.asarray(plan.pmask),
-                    plan.queries_dev, self._scan_cfg)
-            else:
-                packed, pids, remap = fetched
-                od, oi = _scan_streamed_jit(
-                    packed, pids, remap, jnp.asarray(plan.pmask),
-                    plan.queries_dev, self._scan_cfg,
-                    dup_bound=self.dup_bound)
-        else:
-            t.scan_dispatch = time.perf_counter()
-            od, oi = _scan_resident_jit(
-                self.index, plan.queries_dev, jnp.asarray(plan.cids),
-                jnp.asarray(plan.pmask), self._scan_cfg)
+            with region(SPAN_WAIT_GATHER, batch=t.seq):
+                fetched = prep.fut.result()
+        t.scan_dispatch = time.perf_counter()
+        with region(SPAN_DISPATCH, batch=t.seq):
+            od, oi = self._launch_scan(plan, fetched, reference)
         seq = -1
         if self.fresh_source is not None:
             snap = self.fresh_source()
@@ -521,6 +512,31 @@ class PrefetchPipeline:
         return _Inflight(od, oi, plan.nprobe, t, t.size, fresh_seq=seq,
                          queries_host=plan.queries_host)
 
+    def _launch_scan(self, plan: _Plan, fetched, reference: bool):
+        """Enqueue the batch's scan over the streamed rows ``fetched`` (or
+        the resident index when there are none); returns its outputs."""
+        pmask = jnp.asarray(plan.pmask)
+        if not self.streamed:
+            return _scan_resident_jit(
+                self.index, plan.queries_dev, jnp.asarray(plan.cids),
+                pmask, self._scan_cfg)
+        if getattr(self.tier, "quantized", False):
+            if reference:
+                raise ValueError(
+                    "reference scan is an f32-tier A/B baseline; the "
+                    "quantized tier has no pre-runtime twin")
+            q8, scale, norm2, cents, pids, remap = fetched
+            return _scan_streamed_q8_jit(
+                q8, scale, norm2, cents, pids, remap, pmask,
+                plan.queries_dev, self._scan_cfg, dup_bound=self.dup_bound)
+        packed, pids, remap = fetched
+        if reference:
+            return _scan_reference_jit(packed, pids, remap, pmask,
+                                       plan.queries_dev, self._scan_cfg)
+        return _scan_streamed_jit(packed, pids, remap, pmask,
+                                  plan.queries_dev, self._scan_cfg,
+                                  dup_bound=self.dup_bound)
+
     def harvest(self, infl: _Inflight) -> BatchResult:
         """Block on the scan outputs; truncate batch padding.  With the
         flash tier attached, exact-rescore the candidates here — harvest of
@@ -528,8 +544,9 @@ class PrefetchPipeline:
         poller/pipelined drivers dispatch ahead), so the rerank I/O lands
         inside the next scan window by construction, and the stamps prove
         it per run (:func:`rerank_overlap_efficiency`)."""
-        ids = np.asarray(infl.out_i)[: infl.size]
-        dists = np.asarray(infl.out_d)[: infl.size]
+        with region(SPAN_HARVEST, batch=infl.times.seq):
+            ids = np.asarray(infl.out_i)[: infl.size]
+            dists = np.asarray(infl.out_d)[: infl.size]
         infl.times.scan_done = time.perf_counter()
         quality = None
         if self.flash is not None and infl.size > 0:
@@ -538,8 +555,9 @@ class PrefetchPipeline:
             # rerank-agreement proxy costs one (b, k) copy on the hot path
             pre_top = ids[:, : self.cfg.k].copy() if self.quality_proxy \
                 else None
-            dists, ids = self._rerank(
-                infl.queries_host[: infl.size], dists, ids, infl.times)
+            with region(SPAN_RERANK, batch=infl.times.seq):
+                dists, ids = self._rerank(
+                    infl.queries_host[: infl.size], dists, ids, infl.times)
             if pre_top is not None:
                 from repro.obs.quality import recall_proxy
 
@@ -578,7 +596,8 @@ class PrefetchPipeline:
         def _submit(r):
             if r < n_rounds and r not in futs:
                 futs[r] = self._reranker.submit(
-                    self.flash.read, cand_i[:, r * step:(r + 1) * step])
+                    self.flash.read, cand_i[:, r * step:(r + 1) * step],
+                    seq=t.seq)
 
         prev_top = None
         stable = 0

@@ -26,6 +26,8 @@ from typing import List, Optional
 
 import numpy as np
 
+from repro.obs.trace import SPAN_FLASH_READ, region
+
 from .arena import ChunkArena, Extent, LBA_BYTES
 
 
@@ -138,23 +140,26 @@ class FlashTier:
         except OSError:
             pass
 
-    def read(self, ids: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    def read(self, ids: np.ndarray, seq: int = -1
+             ) -> tuple[np.ndarray, np.ndarray]:
         """Read the f32 rows for a batch of candidate ids.
 
         ``ids`` is any-shape int; negative ids (candidate padding) are
         skipped.  Returns (uids (U,) the unique non-negative ids read,
         rows (U, D) f32) — callers remap through uids, mirroring the hot
         tier's union-dedup so a candidate shared across queries costs one
-        flash read per burst.
+        flash read per burst.  ``seq`` is the batch id its profiler span
+        carries (``StageTimes.seq``).
         """
         if self.released:
             raise RuntimeError(
                 f"read on released flash tier (epoch {self.epoch})")
         t0 = time.perf_counter()
-        flat = np.asarray(ids).reshape(-1)
-        requested = int((flat >= 0).sum())
-        uids = np.unique(flat[flat >= 0]).astype(np.int64)
-        rows = np.array(self._mm[uids])  # materialize: touch the "device"
+        with region(SPAN_FLASH_READ, batch=seq):
+            flat = np.asarray(ids).reshape(-1)
+            requested = int((flat >= 0).sum())
+            uids = np.unique(flat[flat >= 0]).astype(np.int64)
+            rows = np.array(self._mm[uids])  # materialize: touch the "device"
         t1 = time.perf_counter()
         nb = int(rows.nbytes)
         self.stats.reads += 1

@@ -31,6 +31,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.obs.trace import SPAN_GATHER, SPAN_H2D, region
+
 
 @dataclasses.dataclass
 class FetchEvent:
@@ -152,6 +154,7 @@ class TieredPostings:
         mask: Optional[np.ndarray] = None,
         pad_rows: Optional[int] = None,
         bucket: int = 1,
+        seq: int = -1,
     ) -> tuple[jax.Array, jax.Array, jax.Array]:
         """Gather the union of probed clusters and stream them once.
 
@@ -164,27 +167,32 @@ class TieredPostings:
         negative probes map to the SENTINEL row (all ids -1, zero payload)
         so downstream id-masking drops them even if a caller forgets the
         probe mask.  Duplicate clusters across queries are fetched once
-        (the paper's burst-overlap observation, §6.2).
+        (the paper's burst-overlap observation, §6.2).  ``seq`` is the
+        batch id its profiler spans carry (``StageTimes.seq``).
         """
         if self.released:
             raise RuntimeError(
                 f"fetch on released tier (epoch {self.epoch}): a batch was "
                 f"routed to a retired index version")
         t0 = time.perf_counter()
-        wanted, u, rows, remap, live = _plan_union(
-            cids, mask, self._lut, self.postings.shape[0], pad_rows, bucket)
-        c, l, d = self.postings.shape
-        # single-copy gather: np.take writes straight into the packed buffer
-        # (no (U, L, D) temporary), and sentinel/pad payload rows stay
-        # uninitialized — their ids are -1, which every consumer masks on.
-        packed = np.empty((rows, l, d), dtype=self.postings.dtype)
-        np.take(self.postings, wanted, axis=0, out=packed[:u])
-        packed_ids = np.full((rows, l), -1, dtype=self.posting_ids.dtype)
-        np.take(self.posting_ids, wanted, axis=0, out=packed_ids[:u])
+        with region(SPAN_GATHER, batch=seq):
+            wanted, u, rows, remap, live = _plan_union(
+                cids, mask, self._lut, self.postings.shape[0], pad_rows,
+                bucket)
+            c, l, d = self.postings.shape
+            # single-copy gather: np.take writes straight into the packed
+            # buffer (no (U, L, D) temporary), and sentinel/pad payload rows
+            # stay uninitialized — their ids are -1, which every consumer
+            # masks on.
+            packed = np.empty((rows, l, d), dtype=self.postings.dtype)
+            np.take(self.postings, wanted, axis=0, out=packed[:u])
+            packed_ids = np.full((rows, l), -1, dtype=self.posting_ids.dtype)
+            np.take(self.posting_ids, wanted, axis=0, out=packed_ids[:u])
         t1 = time.perf_counter()
-        dev_packed = jnp.asarray(packed)
-        dev_ids = jnp.asarray(packed_ids)
-        dev_remap = jnp.asarray(remap.astype(np.int32))
+        with region(SPAN_H2D, batch=seq):
+            dev_packed = jnp.asarray(packed)
+            dev_ids = jnp.asarray(packed_ids)
+            dev_remap = jnp.asarray(remap.astype(np.int32))
         t2 = time.perf_counter()
         nbytes = int(packed.nbytes + packed_ids.nbytes)
         requested = int(live.sum())
@@ -258,6 +266,7 @@ class QuantizedTieredPostings:
         mask: Optional[np.ndarray] = None,
         pad_rows: Optional[int] = None,
         bucket: int = 1,
+        seq: int = -1,
     ):
         """Union-gather the probed clusters' quantized payload.
 
@@ -271,26 +280,28 @@ class QuantizedTieredPostings:
                 f"fetch on released tier (epoch {self.epoch}): a batch was "
                 f"routed to a retired index version")
         t0 = time.perf_counter()
-        wanted, u, rows, remap, live = _plan_union(
-            cids, mask, self._lut, self.q8.shape[0], pad_rows, bucket)
-        c, l, d = self.q8.shape
-        packed_q8 = np.empty((rows, l, d), dtype=self.q8.dtype)
-        np.take(self.q8, wanted, axis=0, out=packed_q8[:u])
-        packed_scale = np.ones((rows,), dtype=np.float32)
-        np.take(self.scale, wanted, axis=0, out=packed_scale[:u])
-        packed_norm2 = np.zeros((rows, l), dtype=np.float32)
-        np.take(self.norm2, wanted, axis=0, out=packed_norm2[:u])
-        packed_cent = np.zeros((rows, d), dtype=np.float32)
-        np.take(self.centroids, wanted, axis=0, out=packed_cent[:u])
-        packed_ids = np.full((rows, l), -1, dtype=self.posting_ids.dtype)
-        np.take(self.posting_ids, wanted, axis=0, out=packed_ids[:u])
+        with region(SPAN_GATHER, batch=seq):
+            wanted, u, rows, remap, live = _plan_union(
+                cids, mask, self._lut, self.q8.shape[0], pad_rows, bucket)
+            c, l, d = self.q8.shape
+            packed_q8 = np.empty((rows, l, d), dtype=self.q8.dtype)
+            np.take(self.q8, wanted, axis=0, out=packed_q8[:u])
+            packed_scale = np.ones((rows,), dtype=np.float32)
+            np.take(self.scale, wanted, axis=0, out=packed_scale[:u])
+            packed_norm2 = np.zeros((rows, l), dtype=np.float32)
+            np.take(self.norm2, wanted, axis=0, out=packed_norm2[:u])
+            packed_cent = np.zeros((rows, d), dtype=np.float32)
+            np.take(self.centroids, wanted, axis=0, out=packed_cent[:u])
+            packed_ids = np.full((rows, l), -1, dtype=self.posting_ids.dtype)
+            np.take(self.posting_ids, wanted, axis=0, out=packed_ids[:u])
         t1 = time.perf_counter()
-        out = (jnp.asarray(packed_q8),
-               jnp.asarray(packed_scale).reshape(rows, 1, 1),
-               jnp.asarray(packed_norm2),
-               jnp.asarray(packed_cent),
-               jnp.asarray(packed_ids),
-               jnp.asarray(remap))
+        with region(SPAN_H2D, batch=seq):
+            out = (jnp.asarray(packed_q8),
+                   jnp.asarray(packed_scale).reshape(rows, 1, 1),
+                   jnp.asarray(packed_norm2),
+                   jnp.asarray(packed_cent),
+                   jnp.asarray(packed_ids),
+                   jnp.asarray(remap))
         t2 = time.perf_counter()
         nbytes = int(packed_q8.nbytes + packed_scale.nbytes
                      + packed_norm2.nbytes + packed_cent.nbytes

@@ -470,3 +470,127 @@ def test_tracing_off_records_nothing_but_metrics_stay_live(small_index):
     assert comps and comps[0].trace_id == 0
     assert eng.obs.trace.snapshot() == []
     assert eng.obs.metrics.counter("engine.completions").value("ok") >= 1
+
+
+# -------------------------------------------------------------------------
+# profiler spans of the serving path (repro.obs.trace.region / SERVE_SPANS)
+# -------------------------------------------------------------------------
+def test_region_is_a_noop_outside_a_profile():
+    import jax
+    from repro.obs.trace import region
+
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    a = region("serve.test", batch=3)
+    assert a is region("serve.other")          # one shared no-op object
+    with a as span:
+        span.set_metadata(batch=4)             # takes args, records nothing
+    assert not isinstance(a, jax.profiler.TraceAnnotation)
+
+
+def test_profile_holds_every_serving_span_on_its_thread(small_index,
+                                                        small_corpus,
+                                                        tmp_path):
+    """A CPU profile of a threaded engine over the q8 pipeline with flash
+    re-rank: every SERVE_SPANS name appears, on the line of the thread the
+    table names, and every batch arg is the StageTimes.seq of a batch the
+    pipeline harvested (and each such batch has each span)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    from repro.obs.trace import SERVE_SPANS, SPAN_IDLE
+    from repro.runtime.pipeline import make_quantized_pipeline
+
+    x, q, _ = small_corpus
+    cfg = SearchConfig(k=5, nprobe_max=8, pruning="none", use_kernel=False,
+                       fused_topk=True)
+    pipe = make_quantized_pipeline(small_index, None, cfg, vectors=x,
+                                   name="q8", pad_batch=8, row_bucket=32,
+                                   flash_path=str(tmp_path / "flash.f32"))
+    seqs = []
+    harvest = pipe.harvest
+
+    def recorded(infl):
+        out = harvest(infl)
+        seqs.append(out.times.seq)
+        return out
+
+    pipe.harvest = recorded
+    pipe.serve_batch(np.asarray(q[:8], np.float32), cfg.k)   # compile
+    seqs.clear()
+    eng = ServeEngine({"q8": pipe},
+                      DynamicBatcher(BatchPolicy(max_batch=8,
+                                                 max_wait_s=0.002, pad=8),
+                                     ["q8"]),
+                      clock=time.perf_counter, depth=2)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path / "prof"), profiler_options=opts)
+    try:
+        eng.start()
+        time.sleep(0.05)                          # the poller idles
+        for burst in range(3):
+            for i in range(6):
+                eng.submit(np.asarray(q[burst * 6 + i], np.float32), cfg.k,
+                           index="q8")
+            assert eng.qp.wait_completions(6 * (burst + 1), timeout=60.0)
+        eng.stop(drain=True)
+    finally:
+        jax.profiler.stop_trace()
+    assert seqs and len(set(seqs)) == len(seqs) and min(seqs) >= 0
+    path = glob.glob(str(tmp_path / "prof" / "**" / "*.xplane.pb"),
+                     recursive=True)[0]
+    seen: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in SERVE_SPANS:
+                    assert line.name.startswith(SERVE_SPANS[ev.name]), \
+                        (ev.name, line.name)
+                    batch = dict(ev.stats).get("batch")
+                    seen.setdefault(ev.name, set()).add(batch)
+    assert set(seen) == set(SERVE_SPANS)
+    assert seen[SPAN_IDLE] == {None}
+    for name, batches in seen.items():
+        if name != SPAN_IDLE:
+            assert batches - {None} == set(seqs), name
+
+
+def test_queue_wait_is_the_batchers_summed_waits():
+    """StageTimes.seq/formed_at/queue_wait_s, copied at formation: under a
+    fixed clock the wait is the sum of MicroBatch.waits."""
+    vt = [0.0]
+    eng = _stub_engine("", clock=lambda: vt[0])
+    formed = []
+    form = eng.batcher.form
+
+    def spy(now, force=False):
+        mb, sheds = form(now, force=force)
+        if mb is not None:
+            formed.append(mb)
+        return mb, sheds
+
+    eng.batcher.form = spy
+    times = []
+    harvest = eng.pipelines["s"].harvest
+
+    def recorded(h):
+        out = harvest(h)
+        times.append(out.times)
+        return out
+
+    eng.pipelines["s"].harvest = recorded
+    for t in (0.0, 0.001, 0.003):
+        vt[0] = t
+        eng.submit(np.zeros(4, np.float32), CFG.k, index="s")
+    eng.step(now=0.010)
+    vt[0] = 0.012
+    eng.submit(np.zeros(4, np.float32), CFG.k, index="s")
+    eng.step(now=0.020)
+    assert [t.seq for t in times] == [0, 1]
+    assert [t.formed_at for t in times] == [0.010, 0.020]
+    assert formed[0].waits.tolist() == pytest.approx([0.010, 0.009, 0.007])
+    for t, mb in zip(times, formed):
+        assert t.queue_wait_s == pytest.approx(float(mb.waits.sum()))
+    assert times[0].queue_wait_s == pytest.approx(0.026)
+    assert times[1].queue_wait_s == pytest.approx(0.008)
