@@ -27,17 +27,23 @@ to HBM:
       clusters") land on adjacent grid steps: Pallas skips the HBM->VMEM DMA
       when the block index repeats, and the per-query selection mask ``qsel``
       routes one block's distances to every query in the tile that probed it.
-      Dead slots (duplicates / masked probes) have an all-false ``qsel``.
+      Dead slots (duplicates / masked probes) have an all-false ``qsel``;
+      a scalar-prefetch ``live`` table marks them, and a dead step computes
+      nothing.
 
     - **In-VMEM running top-k.**  The (bq, k2) candidate block is the
       kernel's accumulator: the output BlockSpec maps every probe step of a
       tile to the same block, so it stays resident in VMEM across the whole
       probe dimension (the standard revisited-output accumulation pattern)
-      and is flushed to HBM exactly once per tile.  Each step merges the
-      fresh (bq, L) distance tile into the accumulator with a k2-pass
-      min-extraction that also suppresses duplicate ids (closure duplicates),
-      so the emitted candidates are unique-by-id with per-id MIN distance —
-      i.e. exactly the first k2 rows of the legacy dedup-top-k.
+      and is flushed to HBM exactly once per tile.  The merge is gated on
+      the running k2-th distance: a live step counts per query the entries
+      strictly below it and runs that many insertion passes (at most k2,
+      none when no entry can enter), each moving one entry into the
+      unsorted accumulator with duplicate-id suppression (closure
+      duplicates); the tile's last step sorts it.  The emitted candidates
+      are unique-by-id with per-id MIN distance — i.e. exactly the first k2
+      rows of the legacy dedup-top-k.  Each tile also emits counters of its
+      live steps, merging steps and insertion passes (:func:`scan_stats`).
 
     - **In-kernel id resolution.**  The global id row (posting_ids) is a
       blocked input indexed by the same block table, so ids never materialize
@@ -267,99 +273,176 @@ def plan_tile_probes(
     return tile_cids, qsel
 
 
-def _extract_topk(acc_d, acc_i, new_d, new_i, k2: int):
-    """k2-pass min-extraction with duplicate-id suppression over the running
-    accumulator (bq, k2) followed by a fresh block (bq, L).
+_FIRST = jnp.iinfo(jnp.int32).min   # below every arrival key
+_LAST = jnp.iinfo(jnp.int32).max    # above every arrival key
 
-    Returns ((bq, k2) dists ascending, (bq, k2) ids); exhausted slots are
-    (+inf, -1).  Each pass takes the global min (ties resolve to the
-    accumulator first, then the lowest column — the order of the two parts
-    laid side by side), emits it, and kills every remaining entry carrying
-    the same id — so the output is unique-by-id with the per-id MIN distance
-    (dedup-top-k semantics; closure duplicates of one vector collapse to a
-    single candidate).  The two parts are never concatenated: a lane concat
-    at an unaligned k2 is a relayout Mosaic need not support, and the
-    emitted column is written with a lane mask for the same reason."""
-    bq = acc_d.shape[0]
-    acol = jax.lax.broadcasted_iota(jnp.int32, acc_d.shape, 1)
-    ncol = jax.lax.broadcasted_iota(jnp.int32, new_d.shape, 1)
-    kcol = jax.lax.broadcasted_iota(jnp.int32, (bq, k2), 1)
-    none = acc_d.shape[1] + new_d.shape[1]
-    out_d = jnp.full((bq, k2), jnp.inf, jnp.float32)
-    out_i = jnp.full((bq, k2), -1, jnp.int32)
+
+def _insert_pass(acc, new_d, new_i, ncol, base):
+    """One pass of the count-bounded merge: move each row's smallest
+    remaining block entry into the unsorted (bq, k2) accumulator.
+
+    ``acc`` is (dists, ids, arrival keys); a key is ``s * L + column`` of
+    the entry's grid step and block column, so ascending (distance, key)
+    is the order in which an ascending merge of the steps would have met
+    the entries (the accumulator wins ties).  An id already held replaces
+    its own slot only when it is strictly smaller (per-id minimum);
+    otherwise the entry replaces the row's last slot in that order when it
+    is strictly before it.  The taken entry and every other copy of its id
+    in the block leave the block."""
+    acc_d, acc_i, acc_a = acc
+    m = jnp.min(new_d, axis=1, keepdims=True)                    # (bq, 1)
+    pos = jnp.min(jnp.where(new_d == m, ncol, new_d.shape[1]), axis=1,
+                  keepdims=True)
+    hit = ncol == pos                                            # one-hot
+    pid = jnp.sum(jnp.where(hit, new_i, 0), axis=1, keepdims=True)
+    same = acc_i == pid                       # ids are unique in acc
+    held = jnp.min(jnp.where(same, acc_d, jnp.inf), axis=1, keepdims=True)
+    top = jnp.max(acc_d, axis=1, keepdims=True)
+    last = jnp.max(jnp.where(acc_d == top, acc_a, _FIRST), axis=1,
+                   keepdims=True)
+    present = held < jnp.inf
+    put = (((same & present) | ((acc_a == last) & ~present))
+           & (m < jnp.where(present, held, top)))
+    acc = (jnp.where(put, m, acc_d), jnp.where(put, pid, acc_i),
+           jnp.where(put, base + pos, acc_a))
+    return acc, jnp.where(hit | (new_i == pid), jnp.inf, new_d)
+
+
+def _sorted_candidates(acc_d, acc_i, acc_a):
+    """The unsorted accumulator as (bq, k2) candidates ascending by
+    (distance, arrival key), padded with (+inf, -1).  Each emitted column
+    is written with a lane mask: a lane slice at an unaligned k2 is a
+    relayout Mosaic need not support."""
+    k2 = acc_d.shape[1]
+    kcol = jax.lax.broadcasted_iota(jnp.int32, acc_d.shape, 1)
+    out_d = jnp.full(acc_d.shape, jnp.inf, jnp.float32)
+    out_i = jnp.full(acc_i.shape, -1, jnp.int32)
     for j in range(k2):
-        m = jnp.minimum(jnp.min(acc_d, axis=1, keepdims=True),
-                        jnp.min(new_d, axis=1, keepdims=True))   # (bq, 1)
-        apos = jnp.min(jnp.where(acc_d == m, acol, none), axis=1,
-                       keepdims=True)
-        npos = jnp.min(jnp.where(new_d == m, ncol, none), axis=1,
-                       keepdims=True)
-        ahit = acol == apos                                      # one-hot
-        nhit = (ncol == npos) & (apos == none)
-        pid = (jnp.sum(jnp.where(ahit, acc_i, 0), axis=1, keepdims=True)
-               + jnp.sum(jnp.where(nhit, new_i, 0), axis=1, keepdims=True))
-        ok = m < jnp.inf
-        out_d = jnp.where((kcol == j) & ok, m, out_d)
-        out_i = jnp.where((kcol == j) & ok, pid, out_i)
-        dup = (pid >= 0) & ok
-        acc_d = jnp.where(ahit | ((acc_i == pid) & dup), jnp.inf, acc_d)
-        new_d = jnp.where(nhit | ((new_i == pid) & dup), jnp.inf, new_d)
+        m = jnp.min(acc_d, axis=1, keepdims=True)
+        first = jnp.min(jnp.where(acc_d == m, acc_a, _LAST), axis=1,
+                        keepdims=True)
+        hit = acc_a == first
+        pid = jnp.sum(jnp.where(hit, acc_i, 0), axis=1, keepdims=True)
+        emit = (kcol == j) & (m < jnp.inf)
+        out_d = jnp.where(emit, m, out_d)
+        out_i = jnp.where(emit, pid, out_i)
+        acc_d = jnp.where(hit, jnp.inf, acc_d)
     return out_d, out_i
 
 
-def _merge_block(d, pids, qsel_ref, od_ref, oi_ref):
-    """Fold one scanned (bq, L) distance block into the resident (bq, k2)
-    candidate accumulator.  ``pids`` (1, L) are the block's global ids;
-    ``qsel_ref`` is the tile's (1, bq, S) query-selection block, whose
-    column ``s`` (this grid step) says which queries of the tile probed the
-    block."""
+def _merge_block(scan, live, pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
+                 arr_ref):
+    """Fold grid step ``s``'s scanned block into the tile's running top-k2.
+
+    ``live`` (a scalar from the plan's table) says whether any query of the
+    tile selects the step's block; a dead step computes nothing.  A live
+    step calls ``scan()`` for its (bq, L) distances, keeps the entries of
+    the queries whose ``qsel_ref`` column ``s`` is set and whose global id
+    (row ``c`` of ``pids_ref``) is not a pad, and counts per row the
+    entries strictly below the row's threshold: the largest distance the
+    accumulator holds, +inf until it is full.  It runs one insertion pass
+    (:func:`_insert_pass`) per such entry, up to k2, for the most such
+    entries of any row, and no pass when there are none.  The accumulator
+    (``od_ref``/``oi_ref``, revisited across the tile, with the arrival
+    keys in ``arr_ref``) is sorted once, at the tile's last step.
+
+    ``st_ref``, the tile's (8, 128) counter block, adds per step: lane 0
+    a live step, lane 1 a step that ran passes, lane 2 the passes."""
     s = pl.program_id(1)
+    k2 = od_ref.shape[-1]
 
     @pl.when(s == 0)
     def _init():
         od_ref[...] = jnp.full(od_ref.shape, jnp.inf, od_ref.dtype)
         oi_ref[...] = jnp.full(oi_ref.shape, -1, oi_ref.dtype)
+        # distinct keys before any real one: an empty slot is the last
+        arr_ref[...] = -1 - jax.lax.broadcasted_iota(jnp.int32,
+                                                     arr_ref.shape, 1)
+        st_ref[...] = jnp.zeros(st_ref.shape, st_ref.dtype)
 
-    qs = qsel_ref[0]                                             # (bq, S)
-    scol = jax.lax.broadcasted_iota(jnp.int32, qs.shape, 1)
-    sel = jnp.max(jnp.where(scol == s, qs, 0), axis=1, keepdims=True) > 0
-    ids = jnp.broadcast_to(pids.astype(jnp.int32), d.shape)
-    d = jnp.where(sel & (ids >= 0), d, jnp.inf)
-    nd, ni = _extract_topk(od_ref[...], oi_ref[...], d, ids,
-                           od_ref.shape[-1])
-    od_ref[...] = nd
-    oi_ref[...] = ni
+    @pl.when(live > 0)
+    def _live():
+        d = scan()                                               # (bq, L)
+        qs = qsel_ref[0]                                         # (bq, S)
+        scol = jax.lax.broadcasted_iota(jnp.int32, qs.shape, 1)
+        sel = jnp.max(jnp.where(scol == s, qs, 0), axis=1, keepdims=True) > 0
+        ids = jnp.broadcast_to(_pick_row(pids_ref, c).astype(jnp.int32),
+                               d.shape)
+        d = jnp.where(sel & (ids >= 0), d, jnp.inf)
+        acc_d = od_ref[...]
+        thr = jnp.max(acc_d, axis=1, keepdims=True)
+        below = jnp.sum((d < thr).astype(jnp.int32), axis=1, keepdims=True)
+        n = jnp.minimum(jnp.max(below, axis=0, keepdims=True), k2)  # (1, 1)
+        lane = jax.lax.broadcasted_iota(jnp.int32, st_ref.shape, 1)
+        st_ref[...] += (jnp.where(lane == 0, 1, 0)
+                        + jnp.where(lane == 1, (n > 0).astype(jnp.int32), 0)
+                        + jnp.where(lane == 2, n, 0))
+        passes = jnp.max(n)
+
+        @pl.when(passes > 0)
+        def _merge():
+            ncol = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+            base = s * d.shape[1]
+            acc, _ = jax.lax.fori_loop(
+                0, passes,
+                lambda _, a: _insert_pass(*a, ids, ncol, base),
+                ((acc_d, oi_ref[...], arr_ref[...]), d))
+            od_ref[...], oi_ref[...], arr_ref[...] = acc
+
+    @pl.when(s == pl.num_programs(1) - 1)
+    def _flush():
+        od_ref[...], oi_ref[...] = _sorted_candidates(
+            od_ref[...], oi_ref[...], arr_ref[...])
 
 
-def _qtile_topk_kernel(tc_ref, q_ref, pids_ref, qsel_ref, post_ref,
-                       od_ref, oi_ref):
-    c = tc_ref[pl.program_id(0), pl.program_id(1)]
-    d = _l2_tile(q_ref[...].astype(jnp.float32),
-                 post_ref[0].astype(jnp.float32))       # (bq, L) — one MXU op
-    _merge_block(d, _pick_row(pids_ref, c), qsel_ref, od_ref, oi_ref)
+def _qtile_topk_kernel(tc_ref, lv_ref, q_ref, pids_ref, qsel_ref, post_ref,
+                       od_ref, oi_ref, st_ref, arr_ref):
+    t = pl.program_id(0)
+    s = pl.program_id(1)
+    _merge_block(
+        lambda: _l2_tile(q_ref[...].astype(jnp.float32),
+                         post_ref[0].astype(jnp.float32)),  # one MXU op
+        lv_ref[t, s], pids_ref, tc_ref[t, s], qsel_ref,
+        od_ref, oi_ref, st_ref, arr_ref)
 
 
 def tile_plan(cids, mask, queries, bq: int, n_clusters: int):
     """Pad the batch to the query tile and build the probe plan shared by
     both fused kernels.  Returns (queries (bp, D), tile_cids (nb, S),
-    qsel (nb, bq, S) int32) — qsel transposed so one (bq, S) block per
-    tile is DMA'd once and stays resident across the tile's S steps."""
+    live (nb, S) int32, qsel (nb, bq, S) int32): ``live`` is 1 where some
+    query of the tile selects the step's block (a scalar-prefetch table),
+    and qsel is transposed so one (bq, S) block per tile is DMA'd once and
+    stays resident across the tile's S steps."""
     padb = (-cids.shape[0]) % bq
     if padb:
         queries = jnp.pad(queries, ((0, padb), (0, 0)))
         cids = jnp.pad(cids, ((0, padb), (0, 0)))
         mask = jnp.pad(jnp.asarray(mask, bool), ((0, padb), (0, 0)))
     tile_cids, qsel = plan_tile_probes(cids, mask, bq, n_clusters)
-    return queries, tile_cids, jnp.swapaxes(qsel, 1, 2)
+    live = jnp.any(qsel > 0, axis=-1).astype(jnp.int32)
+    return queries, tile_cids, live, jnp.swapaxes(qsel, 1, 2)
 
 
-def topk_outputs(nb: int, bq: int, k2: int):
-    """(out_specs, out_shape) of the fused kernels: one (bq, k2) candidate
-    accumulator block per tile for dists and ids, revisited across the
-    tile's probe steps."""
+def topk_call_specs(nb: int, bq: int, k2: int):
+    """(out_specs, out_shape, scratch_shapes) of the fused kernels: per
+    tile one (bq, k2) candidate accumulator block for dists and ids and one
+    (8, 128) counter block, each revisited across the tile's probe steps,
+    and the accumulator's (bq, k2) arrival keys in VMEM."""
     spec = pl.BlockSpec((bq, k2), lambda t, s, *_: (t, 0))
-    return [spec, spec], (jax.ShapeDtypeStruct((nb * bq, k2), jnp.float32),
-                          jax.ShapeDtypeStruct((nb * bq, k2), jnp.int32))
+    stat = pl.BlockSpec((8, 128), lambda t, s, *_: (t, 0))
+    return ([spec, spec, stat],
+            (jax.ShapeDtypeStruct((nb * bq, k2), jnp.float32),
+             jax.ShapeDtypeStruct((nb * bq, k2), jnp.int32),
+             jax.ShapeDtypeStruct((nb * 8, 128), jnp.int32)),
+            [pltpu.VMEM((bq, k2), jnp.int32)])
+
+
+def scan_stats(stat_blocks, n_steps: int):
+    """The kernel's (nb * 8, 128) counter blocks as (4,) int32: grid steps,
+    live steps, steps that ran insertion passes, passes."""
+    per_tile = stat_blocks.reshape(-1, 8, 128)[:, 0, :3]
+    return jnp.concatenate([jnp.full((1,), n_steps, jnp.int32),
+                            jnp.sum(per_tile, axis=0)])
 
 
 @functools.partial(jax.jit, static_argnames=("k2", "bq", "interpret"))
@@ -373,35 +456,38 @@ def ivf_scan_topk(
     k2: int,
     bq: int = 8,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused scan + in-kernel top-k2: returns ((B, k2) dists, (B, k2) ids).
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused scan + in-kernel top-k2: returns ((B, k2) dists, (B, k2) ids,
+    (4,) int32 counters of :func:`scan_stats`).
 
     Candidates are unique-by-id, ascending by distance, padded with
-    (+inf, -1).  Only (B, k2) crosses the pallas_call boundary — never the
-    (B, P, L) distance tensor.
+    (+inf, -1).  Only (B, k2) and the counters cross the pallas_call
+    boundary — never the (B, P, L) distance tensor.
     """
     C, L, D = postings.shape
     B = cids.shape[0]
-    queries, tile_cids, qsel = tile_plan(cids, mask, queries, bq, C)
+    queries, tile_cids, live, qsel = tile_plan(cids, mask, queries, bq, C)
     nb, s_len = tile_cids.shape
-    out_specs, out_shape = topk_outputs(nb, bq, k2)
+    out_specs, out_shape, scratch = topk_call_specs(nb, bq, k2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=2,
         grid=(nb, s_len),
         in_specs=[
-            pl.BlockSpec((bq, D), lambda t, s, tc: (t, 0)),
-            _row_spec(C, L, lambda t, s, tc: tc[t, s]),
-            pl.BlockSpec((1, bq, s_len), lambda t, s, tc: (t, 0, 0)),
-            pl.BlockSpec((1, L, D), lambda t, s, tc: (tc[t, s], 0, 0)),
+            pl.BlockSpec((bq, D), lambda t, s, *_: (t, 0)),
+            _row_spec(C, L, lambda t, s, tc, _: tc[t, s]),
+            pl.BlockSpec((1, bq, s_len), lambda t, s, *_: (t, 0, 0)),
+            pl.BlockSpec((1, L, D), lambda t, s, tc, _: (tc[t, s], 0, 0)),
         ],
         out_specs=out_specs,
+        scratch_shapes=scratch,
     )
-    od, oi = pl.pallas_call(
+    od, oi, st = pl.pallas_call(
         _qtile_topk_kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
         name=TOPK_KERNEL_NAME,
-    )(tile_cids, queries, posting_ids.astype(jnp.int32), qsel, postings)
-    return od[:B], oi[:B]
+    )(tile_cids, live, queries, posting_ids.astype(jnp.int32), qsel,
+      postings)
+    return od[:B], oi[:B], scan_stats(st, nb * s_len)

@@ -32,7 +32,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .ivf_scan import (
-    _dot_t, _merge_block, _pick_row, _row_spec, tile_plan, topk_outputs,
+    _dot_t, _merge_block, _pick_row, _row_spec, scan_stats, tile_plan,
+    topk_call_specs,
 )
 
 # the served scan's op name in the compiled program and the device trace
@@ -101,15 +102,19 @@ def ivf_scan_q8(
 # --------------------------------------------------------------------------
 # fused in-kernel top-k over int8 residual postings
 # --------------------------------------------------------------------------
-def _qtile_topk_q8_kernel(tc_ref, sc_ref, q_ref, cent_ref, norm2_ref,
-                          pids_ref, qsel_ref, q8_ref, od_ref, oi_ref):
+def _qtile_topk_q8_kernel(tc_ref, lv_ref, sc_ref, q_ref, cent_ref,
+                          norm2_ref, pids_ref, qsel_ref, q8_ref, od_ref,
+                          oi_ref, st_ref, arr_ref):
     t = pl.program_id(0)
     s = pl.program_id(1)
     c = tc_ref[t, s]
-    d = _residual_l2(q_ref[...].astype(jnp.float32),
-                     _pick_row(cent_ref, c).astype(jnp.float32), q8_ref[0],
-                     sc_ref[t, s], _pick_row(norm2_ref, c))
-    _merge_block(d, _pick_row(pids_ref, c), qsel_ref, od_ref, oi_ref)
+    _merge_block(
+        lambda: _residual_l2(q_ref[...].astype(jnp.float32),
+                             _pick_row(cent_ref, c).astype(jnp.float32),
+                             q8_ref[0], sc_ref[t, s],
+                             _pick_row(norm2_ref, c)),
+        lv_ref[t, s], pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
+        arr_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("k2", "bq", "interpret"))
@@ -126,38 +131,40 @@ def ivf_scan_q8_topk(
     k2: int,
     bq: int = 8,
     interpret: bool = False,
-) -> tuple[jax.Array, jax.Array]:
-    """Fused q8 scan + in-kernel top-k2: ((B, k2) dists, (B, k2) ids).
+) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """Fused q8 scan + in-kernel top-k2: ((B, k2) dists, (B, k2) ids,
+    (4,) int32 counters), as ivf_scan_topk.
 
     Same candidate contract as ivf_scan_topk; the per-id min collapses the
     slightly-different residual distances of closure duplicates (each copy is
     quantized against its own centroid)."""
     C, L, D = q8.shape
     B = cids.shape[0]
-    queries, tile_cids, qsel = tile_plan(cids, mask, queries, bq, C)
+    queries, tile_cids, live, qsel = tile_plan(cids, mask, queries, bq, C)
     nb, s_len = tile_cids.shape
     step_scale = scale.reshape(C).astype(jnp.float32)[tile_cids]  # (nb, S)
-    out_specs, out_shape = topk_outputs(nb, bq, k2)
+    out_specs, out_shape, scratch = topk_call_specs(nb, bq, k2)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(nb, s_len),
         in_specs=[
             pl.BlockSpec((bq, D), lambda t, s, *_: (t, 0)),
-            _row_spec(C, D, lambda t, s, tc, _: tc[t, s]),
-            _row_spec(C, L, lambda t, s, tc, _: tc[t, s]),
-            _row_spec(C, L, lambda t, s, tc, _: tc[t, s]),
+            _row_spec(C, D, lambda t, s, tc, *_: tc[t, s]),
+            _row_spec(C, L, lambda t, s, tc, *_: tc[t, s]),
+            _row_spec(C, L, lambda t, s, tc, *_: tc[t, s]),
             pl.BlockSpec((1, bq, s_len), lambda t, s, *_: (t, 0, 0)),
-            pl.BlockSpec((1, L, D), lambda t, s, tc, _: (tc[t, s], 0, 0)),
+            pl.BlockSpec((1, L, D), lambda t, s, tc, *_: (tc[t, s], 0, 0)),
         ],
         out_specs=out_specs,
+        scratch_shapes=scratch,
     )
-    od, oi = pl.pallas_call(
+    od, oi, st = pl.pallas_call(
         _qtile_topk_q8_kernel,
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
         name=TOPK_KERNEL_NAME,
-    )(tile_cids, step_scale, queries, centroids, norm2,
+    )(tile_cids, live, step_scale, queries, centroids, norm2,
       posting_ids.astype(jnp.int32), qsel, q8)
-    return od[:B], oi[:B]
+    return od[:B], oi[:B], scan_stats(st, nb * s_len)

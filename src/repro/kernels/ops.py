@@ -173,18 +173,22 @@ def ivf_scan_q8(q8, scale, norm2, centroids, cids, mask, queries):
                            interpret=_interp())
 
 
-def ivf_scan_topk(postings, posting_ids, cids, mask, queries, *, k2, bq=8):
+def ivf_scan_topk(postings, posting_ids, cids, mask, queries, *, k2, bq=8,
+                  with_stats=False):
     """Candidate-compressed scan: fused gather + L2 + in-kernel top-k2.
 
     Returns ((B, k2) dists, (B, k2) ids) — the (B, P, L) distance tensor
-    never crosses the pallas_call boundary."""
-    return _ivf.ivf_scan_topk(postings, posting_ids, cids, mask, queries,
-                              k2=k2, bq=bq, interpret=_interp())
+    never crosses the pallas_call boundary — and, ``with_stats``, the
+    kernel's (4,) int32 counters (``kernels.ivf_scan.scan_stats``)."""
+    out = _ivf.ivf_scan_topk(postings, posting_ids, cids, mask, queries,
+                             k2=k2, bq=bq, interpret=_interp())
+    return out if with_stats else out[:2]
 
 
 def ivf_scan_q8_topk(q8, scale, norm2, centroids, posting_ids, cids, mask,
-                     queries, *, k2, bq=8):
+                     queries, *, k2, bq=8, with_stats=False):
     """Candidate-compressed int8-residual scan (see ivf_scan_topk)."""
-    return _q8.ivf_scan_q8_topk(q8, scale, norm2, centroids, posting_ids,
-                                cids, mask, queries, k2=k2, bq=bq,
-                                interpret=_interp())
+    out = _q8.ivf_scan_q8_topk(q8, scale, norm2, centroids, posting_ids,
+                               cids, mask, queries, k2=k2, bq=bq,
+                               interpret=_interp())
+    return out if with_stats else out[:2]

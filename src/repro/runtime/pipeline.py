@@ -89,6 +89,12 @@ class StageTimes:
     formed_at: float = 0.0         # batcher release time (engine clock)
     queue_wait_s: float = 0.0      # its requests' summed wait from submit
                                    # to formation (MicroBatch.waits)
+    # fused scan kernel counters (kernels.ivf_scan.scan_stats), read back
+    # with the candidates; zeros = the batch ran no fused kernel
+    scan_steps: int = 0            # grid steps: query tiles x probe slots
+    scan_steps_live: int = 0       # steps whose block some query selects
+    scan_steps_merged: int = 0     # steps that ran insertion passes
+    scan_merge_passes: int = 0     # insertion passes run
 
     @property
     def total(self) -> float:
@@ -145,6 +151,7 @@ class _Inflight:
     size: int
     fresh_seq: int = -1
     queries_host: Optional[np.ndarray] = None
+    stats: Optional[jax.Array] = None   # (4,) fused-kernel counters
 
 
 @dataclasses.dataclass(frozen=True)
@@ -211,11 +218,15 @@ def _scan_streamed_jit(packed, packed_ids, remap, pmask, queries,
     all R·L slots.  It is REQUIRED (no default on purpose): the bound must
     cover the build's realized replication or candidates are silently lost —
     PrefetchPipeline derives it from the posting table (max_id_replicas).
+
+    Returns (dists (B, k), ids (B, k), the kernel's (4,) counters or None
+    on the oracle path).
     """
     k2 = cfg.n_cand or _auto_ncand(cfg.k)
+    stats = None
     if cfg.use_kernel:
-        cd, ci = kops.ivf_scan_topk(packed, packed_ids, remap, pmask,
-                                    queries, k2=k2)
+        cd, ci, stats = kops.ivf_scan_topk(packed, packed_ids, remap, pmask,
+                                           queries, k2=k2, with_stats=True)
     else:
         r, l, dim = packed.shape
         b = queries.shape[0]
@@ -229,7 +240,7 @@ def _scan_streamed_jit(packed, packed_ids, remap, pmask, queries,
         m = min(k2 * dup_bound, r * l)
         nd, pos = topk_smallest(d, m)
         cd, ci = dedup_topk(nd, jnp.take_along_axis(ids, pos, axis=-1), k2)
-    return merge_candidate_topk(cd, ci, cfg.k)
+    return (*merge_candidate_topk(cd, ci, cfg.k), stats)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "dup_bound"))
@@ -241,12 +252,14 @@ def _scan_streamed_q8_jit(packed_q8, packed_scale, packed_norm2, packed_cent,
     contract (remap-as-cids for the kernel; one int8->f32 matmul + the
     closed-form residual correction for the oracle).  ``packed_cent`` is
     the owning centroid per packed row (the residual distance form needs
-    it), gathered by the tier alongside the codes."""
+    it), gathered by the tier alongside the codes.  Returns as
+    :func:`_scan_streamed_jit` does."""
     k2 = cfg.n_cand or _auto_ncand(cfg.k)
+    stats = None
     if cfg.use_kernel:
-        cd, ci = kops.ivf_scan_q8_topk(
+        cd, ci, stats = kops.ivf_scan_q8_topk(
             packed_q8, packed_scale, packed_norm2, packed_cent, packed_ids,
-            remap, pmask, queries, k2=k2)
+            remap, pmask, queries, k2=k2, with_stats=True)
     else:
         r, l, dim = packed_q8.shape
         b = queries.shape[0]
@@ -266,7 +279,7 @@ def _scan_streamed_q8_jit(packed_q8, packed_scale, packed_norm2, packed_cent,
         m = min(k2 * dup_bound, r * l)
         nd, pos = topk_smallest(d, m)
         cd, ci = dedup_topk(nd, jnp.take_along_axis(ids, pos, axis=-1), k2)
-    return merge_candidate_topk(cd, ci, cfg.k)
+    return (*merge_candidate_topk(cd, ci, cfg.k), stats)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -495,7 +508,7 @@ class PrefetchPipeline:
                 fetched = prep.fut.result()
         t.scan_dispatch = time.perf_counter()
         with region(SPAN_DISPATCH, batch=t.seq):
-            od, oi = self._launch_scan(plan, fetched, reference)
+            od, oi, stats = self._launch_scan(plan, fetched, reference)
         seq = -1
         if self.fresh_source is not None:
             snap = self.fresh_source()
@@ -510,16 +523,17 @@ class PrefetchPipeline:
                     snap.delta_ids, snap.tombstone, keep)
                 seq = snap.seq
         return _Inflight(od, oi, plan.nprobe, t, t.size, fresh_seq=seq,
-                         queries_host=plan.queries_host)
+                         queries_host=plan.queries_host, stats=stats)
 
     def _launch_scan(self, plan: _Plan, fetched, reference: bool):
         """Enqueue the batch's scan over the streamed rows ``fetched`` (or
-        the resident index when there are none); returns its outputs."""
+        the resident index when there are none); returns its outputs
+        (dists, ids, the fused kernel's counters or None)."""
         pmask = jnp.asarray(plan.pmask)
         if not self.streamed:
-            return _scan_resident_jit(
+            return (*_scan_resident_jit(
                 self.index, plan.queries_dev, jnp.asarray(plan.cids),
-                pmask, self._scan_cfg)
+                pmask, self._scan_cfg), None)
         if getattr(self.tier, "quantized", False):
             if reference:
                 raise ValueError(
@@ -531,8 +545,9 @@ class PrefetchPipeline:
                 plan.queries_dev, self._scan_cfg, dup_bound=self.dup_bound)
         packed, pids, remap = fetched
         if reference:
-            return _scan_reference_jit(packed, pids, remap, pmask,
-                                       plan.queries_dev, self._scan_cfg)
+            return (*_scan_reference_jit(packed, pids, remap, pmask,
+                                         plan.queries_dev, self._scan_cfg),
+                    None)
         return _scan_streamed_jit(packed, pids, remap, pmask,
                                   plan.queries_dev, self._scan_cfg,
                                   dup_bound=self.dup_bound)
@@ -545,9 +560,14 @@ class PrefetchPipeline:
         inside the next scan window by construction, and the stamps prove
         it per run (:func:`rerank_overlap_efficiency`)."""
         with region(SPAN_HARVEST, batch=infl.times.seq):
-            ids = np.asarray(infl.out_i)[: infl.size]
-            dists = np.asarray(infl.out_d)[: infl.size]
+            ids, dists, stats = jax.device_get(
+                (infl.out_i, infl.out_d, infl.stats))
+            ids, dists = ids[: infl.size], dists[: infl.size]
         infl.times.scan_done = time.perf_counter()
+        if stats is not None:
+            (infl.times.scan_steps, infl.times.scan_steps_live,
+             infl.times.scan_steps_merged,
+             infl.times.scan_merge_passes) = (int(v) for v in stats)
         quality = None
         if self.flash is not None and infl.size > 0:
             # pre-rerank approximate top-k (candidates arrive ascending by
@@ -692,7 +712,10 @@ class PrefetchPipeline:
             for rows in range(self.row_bucket, top + self.row_bucket,
                               self.row_bucket):
                 fn, shapes, kw = self._scan_program(bp, rows)
-                fn(*[jnp.zeros(a.shape, a.dtype) for a in shapes], **kw)
+                # the configuration by position, as _launch_scan passes
+                # it: JAX caches a static argument given by keyword apart
+                fn(*[jnp.zeros(a.shape, a.dtype) for a in shapes],
+                   kw["cfg"], dup_bound=kw["dup_bound"])
                 n += 1
         return n + self._warm_fresh(batch_sizes)
 

@@ -43,8 +43,8 @@ def test_ivf_scan_topk_matches_oracle(c, l, d, b, p, bq):
     mask = jax.random.bernoulli(k4, 0.7, (b, p))
     pids = jax.random.randint(k1, (c, l), -1, 4 * c * l)
     k2c = 12
-    gd, gi = ivf_scan_topk(postings, pids, cids, mask, queries,
-                           k2=k2c, bq=bq, interpret=True)
+    gd, gi, _ = ivf_scan_topk(postings, pids, cids, mask, queries,
+                              k2=k2c, bq=bq, interpret=True)
     wd, wi = ref.ivf_scan_topk_ref(postings, pids, cids, mask, queries, k2c)
     assert gd.shape == (b, k2c) and gi.shape == (b, k2c)
     _assert_candidates_match(gd, gi, wd, wi)
@@ -60,8 +60,8 @@ def test_ivf_scan_topk_all_masked_and_dup_probes():
     cids = jnp.full((4, 4), 3, jnp.int32)
     mask = jnp.ones((4, 4), bool).at[0].set(False)
     pids = jnp.arange(8 * 4, dtype=jnp.int32).reshape(8, 4)
-    gd, gi = ivf_scan_topk(postings, pids, cids, mask, queries,
-                           k2=8, bq=2, interpret=True)
+    gd, gi, _ = ivf_scan_topk(postings, pids, cids, mask, queries,
+                              k2=8, bq=2, interpret=True)
     gd, gi = np.asarray(gd), np.asarray(gi)
     assert np.all(np.isinf(gd[0])) and np.all(gi[0] == -1)
     for r in range(1, 4):
@@ -86,8 +86,9 @@ def test_ivf_scan_q8_topk_matches_oracle():
         cids = jax.random.randint(k4, (b, p), 0, c)
         mask = jax.random.bernoulli(k5, 0.8, (b, p))
         pids = jax.random.randint(k4, (c, l), 0, 10_000)
-        gd, gi = ivf_scan_q8_topk(q8, scale, norm2, cents, pids, cids, mask,
-                                  queries, k2=10, bq=bq, interpret=True)
+        gd, gi, _ = ivf_scan_q8_topk(q8, scale, norm2, cents, pids, cids,
+                                     mask, queries, k2=10, bq=bq,
+                                     interpret=True)
         wd, wi = ref.ivf_scan_q8_topk_ref(q8, scale, norm2, cents, pids,
                                           cids, mask, queries, 10)
         _assert_candidates_match(gd, gi, wd, wi, tol=1e-3)
@@ -121,6 +122,271 @@ def test_plan_tile_probes_chunked_parity():
                                   tile_chunk=chunk)
         np.testing.assert_array_equal(np.asarray(tc0), np.asarray(tc))
         np.testing.assert_array_equal(np.asarray(qs0), np.asarray(qs))
+
+
+# -------------------------------------------------------------------------
+# the gated merge against the ungated merge it replaced
+# -------------------------------------------------------------------------
+def _extract_topk_ungated(acc_d, acc_i, new_d, new_i, k2):
+    """The merge the fused kernels ran on every grid step before it was
+    gated: k2 min-extraction passes over the sorted accumulator and the
+    block, ties to the accumulator, then the lowest column; each pass kills
+    every other copy of the emitted id."""
+    bq = acc_d.shape[0]
+    acol = jax.lax.broadcasted_iota(jnp.int32, acc_d.shape, 1)
+    ncol = jax.lax.broadcasted_iota(jnp.int32, new_d.shape, 1)
+    kcol = jax.lax.broadcasted_iota(jnp.int32, (bq, k2), 1)
+    none = acc_d.shape[1] + new_d.shape[1]
+    out_d = jnp.full((bq, k2), jnp.inf, jnp.float32)
+    out_i = jnp.full((bq, k2), -1, jnp.int32)
+    for j in range(k2):
+        m = jnp.minimum(jnp.min(acc_d, axis=1, keepdims=True),
+                        jnp.min(new_d, axis=1, keepdims=True))
+        apos = jnp.min(jnp.where(acc_d == m, acol, none), axis=1,
+                       keepdims=True)
+        npos = jnp.min(jnp.where(new_d == m, ncol, none), axis=1,
+                       keepdims=True)
+        ahit = acol == apos
+        nhit = (ncol == npos) & (apos == none)
+        pid = (jnp.sum(jnp.where(ahit, acc_i, 0), axis=1, keepdims=True)
+               + jnp.sum(jnp.where(nhit, new_i, 0), axis=1, keepdims=True))
+        ok = m < jnp.inf
+        out_d = jnp.where((kcol == j) & ok, m, out_d)
+        out_i = jnp.where((kcol == j) & ok, pid, out_i)
+        dup = (pid >= 0) & ok
+        acc_d = jnp.where(ahit | ((acc_i == pid) & dup), jnp.inf, acc_d)
+        new_d = jnp.where(nhit | ((new_i == pid) & dup), jnp.inf, new_d)
+    return out_d, out_i
+
+
+def _ungated_merge(d, pids, qsel_ref, od_ref, oi_ref):
+    from jax.experimental import pallas as pl
+
+    s = pl.program_id(1)
+
+    @pl.when(s == 0)
+    def _init():
+        od_ref[...] = jnp.full(od_ref.shape, jnp.inf, od_ref.dtype)
+        oi_ref[...] = jnp.full(oi_ref.shape, -1, oi_ref.dtype)
+
+    qs = qsel_ref[0]
+    scol = jax.lax.broadcasted_iota(jnp.int32, qs.shape, 1)
+    sel = jnp.max(jnp.where(scol == s, qs, 0), axis=1, keepdims=True) > 0
+    ids = jnp.broadcast_to(pids.astype(jnp.int32), d.shape)
+    d = jnp.where(sel & (ids >= 0), d, jnp.inf)
+    nd, ni = _extract_topk_ungated(od_ref[...], oi_ref[...], d, ids,
+                                   od_ref.shape[-1])
+    od_ref[...] = nd
+    oi_ref[...] = ni
+
+
+def _ungated_scan(kind, payload, pids, cids, mask, queries, k2, bq):
+    """Today's fused kernels with the ungated merge, in interpret mode."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    from repro.kernels.ivf_scan import _l2_tile, _pick_row, _row_spec, tile_plan
+    from repro.kernels.ivf_scan_q8 import _residual_l2
+
+    C, L, D = payload[0].shape
+    B = cids.shape[0]
+    queries, tc, _, qsel = tile_plan(cids, mask, queries, bq, C)
+    nb, s_len = tc.shape
+    spec = pl.BlockSpec((bq, k2), lambda t, s, *_: (t, 0))
+    out_shape = (jax.ShapeDtypeStruct((nb * bq, k2), jnp.float32),
+                 jax.ShapeDtypeStruct((nb * bq, k2), jnp.int32))
+    tile = pl.BlockSpec((bq, D), lambda t, s, *_: (t, 0))
+    qsel_spec = pl.BlockSpec((1, bq, s_len), lambda t, s, *_: (t, 0, 0))
+    block = pl.BlockSpec((1, L, D), lambda t, s, tc, *_: (tc[t, s], 0, 0))
+    row = lambda w: _row_spec(C, w, lambda t, s, tc, *_: tc[t, s])
+    pids = pids.astype(jnp.int32)
+    if kind == "f32":
+        def kernel(tc_ref, q_ref, pids_ref, qsel_ref, post_ref, od, oi):
+            c = tc_ref[pl.program_id(0), pl.program_id(1)]
+            d = _l2_tile(q_ref[...], post_ref[0])
+            _ungated_merge(d, _pick_row(pids_ref, c), qsel_ref, od, oi)
+        n_scalar, in_specs = 1, [tile, row(L), qsel_spec, block]
+        args = (tc, queries, pids, qsel, payload[0])
+    else:
+        q8, scale, norm2, cents = payload
+        step_scale = scale.reshape(C)[tc]
+
+        def kernel(tc_ref, sc_ref, q_ref, cent_ref, n2_ref, pids_ref,
+                   qsel_ref, q8_ref, od, oi):
+            t, s = pl.program_id(0), pl.program_id(1)
+            c = tc_ref[t, s]
+            d = _residual_l2(q_ref[...], _pick_row(cent_ref, c), q8_ref[0],
+                             sc_ref[t, s], _pick_row(n2_ref, c))
+            _ungated_merge(d, _pick_row(pids_ref, c), qsel_ref, od, oi)
+        n_scalar = 2
+        in_specs = [tile, row(D), row(L), row(L), qsel_spec, block]
+        args = (tc, step_scale, queries, cents, norm2, pids, qsel, q8)
+    od, oi = pl.pallas_call(
+        kernel, out_shape=out_shape, interpret=True,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=n_scalar, grid=(nb, s_len),
+            in_specs=in_specs, out_specs=[spec, spec]),
+    )(*args)
+    return od[:B], oi[:B]
+
+
+def _q8_payload(postings, cents):
+    """int8 residual codes of ``postings`` against their clusters' centroids
+    (the core/quantize layout): (q8, scale (C,1,1), norm2 (C,L), cents)."""
+    r = postings - cents[:, None, :]
+    amax = jnp.max(jnp.abs(r), axis=(1, 2), keepdims=True)
+    scale = jnp.maximum(amax / 127.0, 1e-12)
+    q8 = jnp.clip(jnp.round(r / scale), -127, 127).astype(jnp.int8)
+    norm2 = (scale ** 2)[:, :, 0] * jnp.sum(q8.astype(jnp.float32) ** 2, -1)
+    return q8, scale, norm2, cents
+
+
+def _gated(kind, payload, *probe, k2, bq):
+    fn = ivf_scan_topk if kind == "f32" else ivf_scan_q8_topk
+    return fn(*payload, *probe, k2=k2, bq=bq, interpret=True)
+
+
+def _oracle(kind, payload, *probe, k2):
+    fn = (ref.ivf_scan_topk_ref if kind == "f32"
+          else ref.ivf_scan_q8_topk_ref)
+    return fn(*payload, *probe, k2)
+
+
+def _merge_case(name):
+    """(postings, cents, pids, cids, mask, queries, k2, bq) of one case."""
+    rng = np.random.default_rng(sum(map(ord, name)))
+    c, l, d, b, bq, k2 = 12, 8, 16, 8, 4, 6
+    cents = rng.normal(size=(c, d)).astype(np.float32) * 2.0
+    post = cents[:, None, :] + rng.normal(size=(c, l, d)).astype(np.float32)
+    pids = np.arange(c * l, dtype=np.int32).reshape(c, l)
+    q = rng.normal(size=(b, d)).astype(np.float32)
+    cids = np.tile(np.arange(c, dtype=np.int32), (b, 1))
+    mask = np.ones((b, c), bool)
+    if name == "random":
+        b, bq, k2 = 13, 4, 10
+        q = rng.normal(size=(b, d)).astype(np.float32)
+        cids = rng.integers(0, c, size=(b, 7)).astype(np.int32)
+        mask = rng.random((b, 7)) < 0.7
+        pids = rng.integers(-1, 2 * c * l, size=(c, l)).astype(np.int32)
+    elif name.startswith("closure_dup"):
+        # id 999 is held by cluster 2 (an early step) and cluster 9 (a later
+        # one); both copies lie far below every query's k2-th distance
+        u = rng.normal(size=d).astype(np.float32)
+        u /= np.linalg.norm(u)
+        near, far = (0.5, 0.1) if name == "closure_dup_later_smaller" \
+            else (0.1, 0.3)
+        post[2, 0] = q[0] + near * u
+        post[9, 3] = q[0] + far * u
+        pids[2, 0] = pids[9, 3] = 999
+    elif name in ("best_first", "best_last"):
+        src = 0 if name == "best_first" else c - 1
+        q = post[src, :b] + 0.01 * rng.normal(size=(b, d)).astype(np.float32)
+        k2 = 4
+    elif name == "padded_tile":
+        mask[bq:] = False                  # the second tile is all pad rows
+    elif name == "all_masked":
+        mask[:] = False
+    elif name == "k2_over_live":
+        cids, mask, k2 = cids[:, :3], mask[:, :3], 64
+        pids[1, 5:] = -1                   # pad slots are never candidates
+    elif name == "boundary_ties":
+        # every entry of a query is the same distance: the k2-th slot
+        # falls inside a tie group spanning every step
+        post[:] = post[0, 0]
+        cents[:] = cents[0]
+        k2 = 5
+    return (jnp.asarray(post), jnp.asarray(cents), jnp.asarray(pids),
+            jnp.asarray(cids), jnp.asarray(mask), jnp.asarray(q), k2, bq)
+
+
+MERGE_CASES = ["random", "closure_dup_later_smaller",
+               "closure_dup_later_larger", "best_first", "best_last",
+               "padded_tile", "all_masked", "k2_over_live", "boundary_ties"]
+
+
+@pytest.mark.parametrize("kind", ["f32", "q8"])
+@pytest.mark.parametrize("case", MERGE_CASES)
+def test_gated_merge_matches_ungated(case, kind):
+    """The gated kernels emit exactly what the ungated merge emitted (same
+    distances, same ids in the same order, ties included) and agree with
+    the oracle up to order inside tie groups; their counters obey
+    steps = nb * S >= live >= merged and merged <= passes <= k2 * merged."""
+    from repro.kernels.ivf_scan import tile_plan
+
+    post, cents, pids, cids, mask, q, k2, bq = _merge_case(case)
+    payload = (post,) if kind == "f32" else _q8_payload(post, cents)
+    probe = (pids, cids, mask, q)
+    gd, gi, st = _gated(kind, payload, *probe, k2=k2, bq=bq)
+    ud, ui = _ungated_scan(kind, payload, pids, cids, mask, q, k2, bq)
+    np.testing.assert_array_equal(np.asarray(gd), np.asarray(ud))
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(ui))
+    wd, wi = _oracle(kind, payload, *probe, k2=k2)
+    if case == "boundary_ties":
+        np.testing.assert_allclose(np.asarray(gd), np.asarray(wd), rtol=1e-5)
+    else:
+        _assert_candidates_match(gd, gi, wd, wi,
+                                 tol=1e-4 if kind == "f32" else 1e-3)
+    _, tc, live, _ = tile_plan(cids, mask, q, bq, post.shape[0])
+    steps, n_live, merged, passes = np.asarray(st).tolist()
+    assert steps == tc.size
+    assert n_live == int(np.asarray(live).sum())
+    assert merged <= n_live <= steps
+    assert merged <= passes <= k2 * merged
+    if case == "all_masked":
+        assert (n_live, merged, passes) == (0, 0, 0)
+        assert np.all(np.asarray(gi) == -1)
+    if case == "k2_over_live":             # the threshold never leaves +inf
+        assert merged == n_live
+
+
+@pytest.mark.parametrize("kind", ["f32", "q8"])
+@pytest.mark.parametrize("setup,want", [
+    ("every_block_enters", [6, 3, 3, 12]),
+    ("first_block_fills", [6, 3, 1, 1]),
+    ("all_masked", [6, 0, 0, 0]),
+])
+def test_scan_counters_on_a_known_plan(kind, setup, want):
+    """Two queries probing clusters 0, 1, 2 in one tile: 6 grid steps, 3
+    live blocks of 4 entries.  With k2 above the 12 entries every live step
+    merges with 4 passes; with k2 = 1 and each query a vector of cluster 0,
+    cluster 0 fills the accumulator with 1 pass and no later entry is below
+    its distance."""
+    rng = np.random.default_rng(5)
+    c, l, d = 3, 4, 8
+    cents = rng.normal(size=(c, d)).astype(np.float32) * 4.0
+    post = cents[:, None, :] + rng.normal(size=(c, l, d)).astype(np.float32)
+    pids = np.arange(c * l, dtype=np.int32).reshape(c, l)
+    cids = np.tile(np.arange(c, dtype=np.int32), (2, 1))
+    mask = np.full((2, c), setup != "all_masked")
+    k2 = 1 if setup == "first_block_fills" else 16
+    q = post[0, :2].copy() if setup == "first_block_fills" \
+        else rng.normal(size=(2, d)).astype(np.float32)
+    post, cents = jnp.asarray(post), jnp.asarray(cents)
+    payload = (post,) if kind == "f32" else _q8_payload(post, cents)
+    _, _, st = _gated(kind, payload, jnp.asarray(pids), jnp.asarray(cids),
+                      jnp.asarray(mask), jnp.asarray(q), k2=k2, bq=2)
+    assert np.asarray(st).tolist() == want
+
+
+def test_merge_step_share_reader():
+    """``scan.merge_step_share`` reads the counters as a percentage, and
+    nothing from batches that lack them (a program without the counters)."""
+    import importlib.util
+    import os
+    import types
+
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "metrics",
+                        "scan.merge_step_share.py")
+    spec = importlib.util.spec_from_file_location("merge_step_share", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    ns = types.SimpleNamespace
+    run = ns(batches=[ns(scan_steps=2048, scan_steps_merged=100),
+                      ns(scan_steps=4096, scan_steps_merged=207)])
+    assert mod.read(run) == pytest.approx(100.0 * 307 / 6144)
+    assert mod.read(ns(batches=[ns(size=3), ns(size=5)])) is None
+    assert mod.read(ns(batches=[])) is None
+    assert mod.read(ns(batches=[ns(scan_steps=0,
+                                   scan_steps_merged=0)])) is None
 
 
 # -------------------------------------------------------------------------

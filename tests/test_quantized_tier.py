@@ -232,8 +232,16 @@ def test_no_rerank_arm_serves_quantized_distances(small_index, small_corpus):
     assert recall_at_k(res.ids[:, :10], np.asarray(t10)) >= 0.9
 
 
-def test_q8_pipeline_warmup_compiles(q8_pipeline):
+def test_q8_pipeline_warmup_compiles(q8_pipeline, small_corpus):
+    """Warm-up compiles the scan as a served batch calls it, so the batch
+    served after it traces no new scan program."""
+    from repro.runtime import pipeline as pp
+
     assert q8_pipeline.warmup(batch_sizes=(8,)) >= 1
+    before = pp._scan_streamed_q8_jit._cache_size()
+    _, q, topk = small_corpus
+    q8_pipeline.serve_batch(q[:5], topk[:5])
+    assert pp._scan_streamed_q8_jit._cache_size() == before
 
 
 # -------------------------------------------------------------------------

@@ -207,6 +207,45 @@ def test_dup_bound_derived_from_build_replication():
     assert (out8.ids < 0).any(), "dup_bound=8 should starve k=10 here"
 
 
+@pytest.mark.parametrize("tier_kind", ["f32", "q8"])
+def test_kernel_pipeline_stamps_scan_counters(small_index, queries,
+                                              tier_kind):
+    """A streamed batch served through a fused kernel carries the kernel's
+    counters, read back with its candidates, in its StageTimes; the jnp
+    oracle path runs no kernel and leaves them zero."""
+    import dataclasses
+
+    from repro.core.quantize import quantize_postings
+    from repro.storage import QuantizedTieredPostings
+
+    if tier_kind == "f32":
+        tier = TieredPostings(np.asarray(small_index.postings),
+                              np.asarray(small_index.posting_ids))
+    else:
+        qp = quantize_postings(small_index.postings, small_index.centroids,
+                               small_index.posting_ids)
+        tier = QuantizedTieredPostings(
+            np.asarray(qp.q8), np.asarray(qp.scale), np.asarray(qp.norm2),
+            np.asarray(small_index.centroids),
+            np.asarray(small_index.posting_ids))
+    times = {}
+    for use_kernel in (False, True):
+        cfg = dataclasses.replace(CFG, use_kernel=use_kernel)
+        pipe = PrefetchPipeline(small_index, None, cfg, tier=tier,
+                                pad_batch=8, row_bucket=32)
+        times[use_kernel] = pipe.serve_batch(queries[0][:5], 5).times
+    t = times[True]
+    # one tile of 8 padded queries x nprobe_max probe slots each
+    assert t.scan_steps == 8 * CFG.nprobe_max
+    assert 0 < t.scan_steps_merged <= t.scan_steps_live <= t.scan_steps
+    k2 = 16                                # the auto candidate width at k=5
+    assert t.scan_steps_merged <= t.scan_merge_passes \
+        <= k2 * t.scan_steps_merged
+    o = times[False]
+    assert (o.scan_steps, o.scan_steps_live, o.scan_steps_merged,
+            o.scan_merge_passes) == (0, 0, 0, 0)
+
+
 # -------------------------------------------------------------------------
 # batcher: deadline-estimate fixed point, shared due predicate, locality
 # -------------------------------------------------------------------------

@@ -5,7 +5,9 @@ that Mosaic refuses.  These tests hand the TPU compiler a described (not
 attached) v5e chip and compile each kernel at the serving width: D=128,
 posting lists of L=128, C=4096 clusters, a batch of 64 queries, 16 probes
 and the q8 pipeline's candidate width k2=24 (k=10).  A compiled program
-that holds ``tpu_custom_call`` kept its Mosaic kernel.
+that holds ``tpu_custom_call`` kept its Mosaic kernel.  The fused top-k
+kernels also compile at the served shapes: a padded batch of 16 or 32
+queries at nprobe 128, with their counters among the outputs.
 
 The topology is described inside a module fixture, never at import time:
 only one process at a time may load the TPU library, and every test worker
@@ -19,6 +21,7 @@ jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
 C, L, D, B, P, K2 = 4096, 128, 128, 64, 16, 24
+SERVE_B, SERVE_P = (16, 32), 128
 K_CENTS = 1024
 
 
@@ -55,7 +58,19 @@ def _scan_cases():
     i32, f, b8, i8 = jnp.int32, jnp.float32, jnp.bool_, jnp.int8
     probes = [((B, P), i32), ((B, P), b8), ((B, D), f)]
     q8_payload = [((C, L, D), i8), ((C, 1, 1), f), ((C, L), f), ((C, D), f)]
-    return {
+
+    def served(b):
+        return [((b, SERVE_P), i32), ((b, SERVE_P), b8), ((b, D), f)]
+
+    served_cases = {}
+    for b in SERVE_B:
+        served_cases[f"ivf_scan_topk_b{b}"] = (
+            lambda *a: f32.ivf_scan_topk(*a, k2=K2),
+            [((C, L, D), f), ((C, L), i32)] + served(b))
+        served_cases[f"ivf_scan_q8_topk_b{b}"] = (
+            lambda *a: q8.ivf_scan_q8_topk(*a, k2=K2),
+            q8_payload + [((C, L), i32)] + served(b))
+    return served_cases | {
         "ivf_scan_topk": (
             lambda *a: f32.ivf_scan_topk(*a, k2=K2),
             [((C, L, D), f), ((C, L), i32)] + probes),
@@ -78,8 +93,13 @@ def _scan_cases():
 
 @pytest.mark.parametrize("name", [
     "ivf_scan_topk", "ivf_scan_q8_topk", "ivf_scan", "ivf_scan_q8",
-    "ivf_scan_clustermajor", "kmeans_assign_update", "kmeans_mstep"])
+    "ivf_scan_clustermajor", "kmeans_assign_update", "kmeans_mstep",
+    "ivf_scan_topk_b16", "ivf_scan_topk_b32", "ivf_scan_q8_topk_b16",
+    "ivf_scan_q8_topk_b32"])
 def test_kernel_compiles_for_v5e(one_chip, name):
     fn, specs = _scan_cases()[name]
     compiled = jax.jit(fn).lower(*_shapes(one_chip, *specs)).compile()
     assert "tpu_custom_call" in compiled.as_text(), name
+    if "topk" in name:                     # candidates and the counters
+        d, i, stats = compiled.out_info
+        assert d.shape == i.shape and stats.shape == (4,), name
