@@ -40,10 +40,16 @@ to HBM:
       strictly below it and runs that many insertion passes (at most k2,
       none when no entry can enter), each moving one entry into the
       unsorted accumulator with duplicate-id suppression (closure
-      duplicates); the tile's last step sorts it.  The emitted candidates
-      are unique-by-id with per-id MIN distance — i.e. exactly the first k2
-      rows of the legacy dedup-top-k.  Each tile also emits counters of its
-      live steps, merging steps and insertion passes (:func:`scan_stats`).
+      duplicates); the tile's last step sorts it.  Past ``NARROW_K2``
+      (k2 > 128, a search service's k) the merge is buffered instead: the
+      entries below the threshold are appended to a VMEM stack that
+      bitonic compactions keep to the k2 best distinct ids, so a live step
+      costs a bounded number of passes and the compiled kernel does not
+      grow with k2 (:func:`_append_wide`, :func:`_settle_wide`).  The
+      emitted candidates are unique-by-id with per-id MIN distance — i.e.
+      exactly the first k2 rows of the legacy dedup-top-k.  Each tile also
+      emits counters of its live steps, merging steps, admitted entries
+      and compaction passes (:func:`scan_stats`).
 
     - **In-kernel id resolution.**  The global id row (posting_ids) is a
       blocked input indexed by the same block table, so ids never materialize
@@ -274,7 +280,16 @@ def plan_tile_probes(
 
 
 _FIRST = jnp.iinfo(jnp.int32).min   # below every arrival key
-_LAST = jnp.iinfo(jnp.int32).max    # above every arrival key
+_LAST = jnp.iinfo(jnp.int32).max    # above every arrival key and id
+_LANES = 128
+
+# Widest k2 the per-entry insertion merge serves.  Its live step runs one
+# pass over the (bq, k2) accumulator per entry that can enter, and its last
+# step k2 unrolled extraction passes, which is cheap while the accumulator
+# is one lane tile; a wider k2 takes the buffered merge (:func:`_append_wide`),
+# whose live step costs a fixed number of passes over the block and whose
+# compiled size does not grow with k2.
+NARROW_K2 = _LANES
 
 
 def _insert_pass(acc, new_d, new_i, ncol, base):
@@ -330,8 +345,272 @@ def _sorted_candidates(acc_d, acc_i, acc_a):
     return out_d, out_i
 
 
+# ---- the buffered merge for k2 > NARROW_K2 ------------------------------
+#
+# The accumulator is a (W/128 + 1, bq, 128) stack of lane tiles per array
+# (distances, ids, arrival keys), W = 2 * next_pow2(k2 rounded to 128):
+# positions c * 128 + lane, the last tile a spill for unaligned appends.
+# A live step appends the entries below the row's threshold at a shared
+# fill point; a step after which the next block might not fit, and the
+# tile's last step, compact: sort by (id, distance, key), drop every copy
+# of an id after its first, sort by (distance, key), keep the first k2
+# and set the threshold to the k2-th.  Every comparison is a strict
+# lexicographic order on tuples that differ for every entry that can be
+# emitted, so the sorts need no stability.  A bitonic network sorts:
+# within a lane tile with lane rotations, across tiles with tile pairs,
+# every stage a loop step, so the compiled size depends on neither W nor
+# the tile's 128 lanes.
+
+def _less(a, b):
+    """Strict lexicographic ``a < b`` over tuples of equal-shape arrays."""
+    lt = a[-1] < b[-1]
+    for x, y in zip(a[-2::-1], b[-2::-1]):
+        lt = (x < y) | ((x == y) & lt)
+    return lt
+
+
+def _exchange(vals, partner, nkey, lower, up):
+    """One compare-exchange step: each lane keeps the smaller of itself and
+    its partner (by the first ``nkey`` arrays) when it is the lower lane of
+    an ascending pair or the upper lane of a descending one."""
+    keep = _less(vals[:nkey], partner[:nkey]) == (lower == up)
+    return tuple(jnp.where(keep, x, y) for x, y in zip(vals, partner))
+
+
+def _tile_stage(vals, nkey, j, up):
+    """Compare-exchange at lane distance ``j`` < 128 (a traced scalar)
+    within each tile."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, vals[0].shape, 1)
+    upper = (lane & j) != 0
+    partner = tuple(jnp.where(upper, pltpu.roll(x, j, 1),
+                              pltpu.roll(x, _LANES - j, 1)) for x in vals)
+    return _exchange(vals, partner, nkey, ~upper, up)
+
+
+def _merge_tile(vals, nkey, up, log_size=_LANES.bit_length() - 1):
+    """Bitonic merge within each tile: each run of ``2**log_size`` lanes
+    that is bitonic comes out sorted, ascending where ``up``.  One stage a
+    loop step, so the trace holds one stage whatever the size."""
+    def stage(b, vals):                    # lane distance 2**(log_size-1-b)
+        return _tile_stage(vals, nkey, jnp.left_shift(1, log_size - 1 - b),
+                           up)
+
+    return jax.lax.fori_loop(0, log_size, stage, vals)
+
+
+def _sort_tile(vals, nkey, ascending):
+    """Bitonic sort of each row's 128 lanes, ascending or descending: runs
+    of 2, 4, ... 128 lanes merged in turn, in a loop."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, vals[0].shape, 1)
+    top = _LANES.bit_length() - 1
+    flip = jnp.logical_not(ascending)
+
+    def level(a, vals):                    # runs of 2**a lanes
+        up = ((lane & jnp.left_shift(1, a)) == 0) != ((a == top) & flip)
+        return _merge_tile(vals, nkey, up, a)
+
+    return jax.lax.fori_loop(1, top + 1, level, vals)
+
+
+def _sort_stack(refs, nkey, n_tiles):
+    """Bitonic sort, ascending by the first ``nkey`` arrays, of the first
+    ``n_tiles`` (a power of two) lane tiles of ``refs`` read as one row of
+    ``n_tiles * 128`` positions per query."""
+    def load(c):
+        return tuple(r[c] for r in refs)
+
+    def store(c, vals):
+        for r, v in zip(refs, vals):
+            r[c] = v
+
+    def sort_tile(c, carry):
+        store(c, _sort_tile(load(c), nkey, c % 2 == 0))
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, sort_tile, 0)
+    levels = n_tiles.bit_length() - 1
+
+    def level(a, carry):                   # runs of 2**a tiles merged
+        def across(b, carry):              # tile distance 2**(a - 1 - b)
+            sh = a - 1 - b
+
+            def pair(i, carry):
+                lo = ((i >> sh) << (sh + 1)) | (i & ((1 << sh) - 1))
+                hi = lo + (1 << sh)
+                x, y = load(lo), load(hi)
+                keep = _less(x[:nkey], y[:nkey]) == (((lo >> a) & 1) == 0)
+                store(lo, tuple(jnp.where(keep, u, v) for u, v in zip(x, y)))
+                store(hi, tuple(jnp.where(keep, v, u) for u, v in zip(x, y)))
+                return carry
+
+            return jax.lax.fori_loop(0, n_tiles // 2, pair, carry)
+
+        jax.lax.fori_loop(0, a, across, 0)
+
+        def merge_tile(c, carry):
+            store(c, _merge_tile(load(c), nkey, ((c >> a) & 1) == 0))
+            return carry
+
+        return jax.lax.fori_loop(0, n_tiles, merge_tile, carry)
+
+    jax.lax.fori_loop(1, levels + 1, level, 0)
+
+
+def _sort_passes(n_tiles: int) -> int:
+    """Compare-exchange passes of a bitonic sort over ``n_tiles`` tiles."""
+    lg = (n_tiles * _LANES).bit_length() - 1
+    return lg * (lg + 1) // 2
+
+
+def _compact(acc_refs, k2: int):
+    """Keep each row's k2 best distinct ids: sort the stack by (id,
+    distance, key) with empty slots last, drop each id's later copies,
+    sort by (distance, key) and empty every position from k2 on.  Returns
+    the number of passes over the stack it ran."""
+    d_ref, i_ref, a_ref = acc_refs
+    n_tiles = d_ref.shape[0] - 1
+
+    def empty_last(c, carry):
+        i_ref[c] = jnp.where(d_ref[c] < jnp.inf, i_ref[c], _LAST)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, empty_last, 0)
+    _sort_stack((i_ref, d_ref, a_ref), 3, n_tiles)
+
+    def first_copies(r, carry):            # from the last tile down, so
+        c = n_tiles - 1 - r                # each reads an unchanged left
+        ids = i_ref[c]                     # neighbour
+        lane = jax.lax.broadcasted_iota(jnp.int32, ids.shape, 1)
+        prev = jnp.where(lane == 0,
+                         pltpu.roll(i_ref[jnp.maximum(c - 1, 0)], 1, 1),
+                         pltpu.roll(ids, 1, 1))
+        has_prev = (lane > 0) | (c > 0)
+        dup = has_prev & (prev == ids)
+        keep = (ids != _LAST) & ~dup
+        d_ref[c] = jnp.where(keep, d_ref[c], jnp.inf)
+        i_ref[c] = jnp.where(keep, ids, -1)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, first_copies, 0)
+    _sort_stack((d_ref, a_ref, i_ref), 2, n_tiles)
+
+    def cut(c, carry):
+        pos = c * _LANES + jax.lax.broadcasted_iota(
+            jnp.int32, d_ref.shape[1:], 1)
+        d_ref[c] = jnp.where(pos < k2, d_ref[c], jnp.inf)
+        i_ref[c] = jnp.where(pos < k2, i_ref[c], -1)
+        return carry
+
+    jax.lax.fori_loop(0, n_tiles, cut, 0)
+    return 2 * _sort_passes(n_tiles) + 3
+
+
+def _stack_tiles(k2: int) -> int:
+    """Lane tiles the buffered merge sorts: a power of two, at least twice
+    the tiles k2 fills, so a compaction leaves room for many appends."""
+    tiles = -(-k2 // _LANES)
+    return 2 * (1 << (tiles - 1).bit_length())
+
+
+def _append_wide(d, ids, s, st_ref, scratch):
+    """A live step of the buffered merge (k2 > NARROW_K2): ``d`` (bq, L)
+    are the step's masked distances and ``ids`` their global ids;
+    ``scratch`` holds the accumulator stack, the fill point (SMEM) and the
+    per-row threshold.  Each row's entries below its threshold are sorted
+    to the front of a lane tile and appended at the shared fill point;
+    :func:`_settle_wide` keeps room for a whole block.  Counts into
+    ``st_ref`` as :func:`_merge_block` says."""
+    d_ref, i_ref, a_ref, fill_ref, thr_ref = scratch
+    bq, l = d.shape
+    keys = s * l + jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    below = d < thr_ref[:, :l]
+    n = jnp.max(jnp.sum(below.astype(jnp.int32), axis=1, keepdims=True),
+                axis=0, keepdims=True)                           # (1, 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, st_ref.shape, 1)
+    st_ref[...] += (jnp.where(lane == 0, 1, 0)
+                    + jnp.where(lane == 1, (n > 0).astype(jnp.int32), 0)
+                    + jnp.where(lane == 2, n, 0))
+    count = jnp.max(n)
+
+    @pl.when(count > 0)
+    def _append():
+        vals = (jnp.where(below, d, jnp.inf), keys,
+                jnp.where(below, ids, -1))
+        if l < _LANES:                     # short lists (interpret only)
+            pad = ((0, 0), (0, _LANES - l))
+            vals = (jnp.pad(vals[0], pad, constant_values=jnp.inf),
+                    jnp.pad(vals[1], pad), jnp.pad(vals[2], pad,
+                                                   constant_values=-1))
+        d_s, k_s, i_s = _sort_tile(vals, 2, True)  # entries below first
+        fill = fill_ref[0]
+        c0, r = fill // _LANES, fill % _LANES
+        tl = jax.lax.broadcasted_iota(jnp.int32, d_s.shape, 1)
+        for ref, v in zip((d_ref, i_ref, a_ref), (d_s, i_s, k_s)):
+            v = pltpu.roll(v, r, 1)
+            ref[c0] = jnp.where(tl >= r, v, ref[c0])
+            ref[c0 + 1] = jnp.where(tl < r, v, ref[c0 + 1])
+        fill_ref[0] = fill + count
+
+
+def _settle_wide(last, od_ref, oi_ref, st_ref, scratch):
+    """End of every step of the buffered merge, and its one compaction
+    site: compact when the next block might not fit, or at the tile's last
+    step, which then writes the first k2 positions as the tile's
+    candidates.  A compaction sets each row's threshold to its k2-th
+    distance and the fill point to k2, which leaves room for a block."""
+    d_ref, i_ref, a_ref, fill_ref, thr_ref = scratch
+    k2 = od_ref.shape[-1]
+    n_tiles = d_ref.shape[0] - 1
+
+    @pl.when(last | (fill_ref[0] > (n_tiles - 1) * _LANES))
+    def _compact_stack():
+        passes = _compact((d_ref, i_ref, a_ref), k2)
+        c, r = (k2 - 1) // _LANES, (k2 - 1) % _LANES
+        rl = jax.lax.broadcasted_iota(jnp.int32, thr_ref.shape, 1)
+        thr = jnp.max(jnp.where(rl == r, d_ref[c], -jnp.inf), axis=1,
+                      keepdims=True)
+        thr_ref[...] = jnp.broadcast_to(thr, thr_ref.shape)
+        fill_ref[0] = k2
+        lane = jax.lax.broadcasted_iota(jnp.int32, st_ref.shape, 1)
+        st_ref[...] += jnp.where(lane == 3, passes, 0)
+
+    @pl.when(last)
+    def _emit():
+        full, rest = divmod(k2, _LANES)
+
+        def emit(c, carry):
+            at = pl.ds(pl.multiple_of(c * _LANES, _LANES), _LANES)
+            od_ref[:, at] = d_ref[c]
+            oi_ref[:, at] = i_ref[c]
+            return carry
+
+        if full:
+            jax.lax.fori_loop(0, full, emit, 0)
+        if rest:
+            od_ref[:, full * _LANES:] = d_ref[full][:, :rest]
+            oi_ref[:, full * _LANES:] = i_ref[full][:, :rest]
+
+
+def _init_wide(scratch):
+    """An empty stack (distinct keys below every real one), fill point 0
+    and threshold +inf."""
+    d_ref, i_ref, a_ref, fill_ref, thr_ref = scratch
+    shape = d_ref.shape[1:]
+    pos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def clear(c, carry):
+        d_ref[c] = jnp.full(shape, jnp.inf, jnp.float32)
+        i_ref[c] = jnp.full(shape, -1, jnp.int32)
+        a_ref[c] = -1 - (c * _LANES + pos)  # distinct, before any real key
+        return carry
+
+    jax.lax.fori_loop(0, d_ref.shape[0], clear, 0)
+    fill_ref[0] = 0
+    thr_ref[...] = jnp.full(thr_ref.shape, jnp.inf, jnp.float32)
+
+
 def _merge_block(scan, live, pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
-                 arr_ref):
+                 scratch):
     """Fold grid step ``s``'s scanned block into the tile's running top-k2.
 
     ``live`` (a scalar from the plan's table) says whether any query of the
@@ -339,26 +618,39 @@ def _merge_block(scan, live, pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
     step calls ``scan()`` for its (bq, L) distances, keeps the entries of
     the queries whose ``qsel_ref`` column ``s`` is set and whose global id
     (row ``c`` of ``pids_ref``) is not a pad, and counts per row the
-    entries strictly below the row's threshold: the largest distance the
-    accumulator holds, +inf until it is full.  It runs one insertion pass
-    (:func:`_insert_pass`) per such entry, up to k2, for the most such
+    entries strictly below the row's threshold.
+
+    Up to ``NARROW_K2`` the threshold is the largest distance the
+    accumulator holds (+inf until it is full), and the step runs one
+    insertion pass (:func:`_insert_pass`) per such entry, for the most such
     entries of any row, and no pass when there are none.  The accumulator
     (``od_ref``/``oi_ref``, revisited across the tile, with the arrival
-    keys in ``arr_ref``) is sorted once, at the tile's last step.
+    keys in ``scratch``) is sorted once, at the tile's last step.  Above
+    it, the buffered merge appends the entries below the threshold its
+    last compaction left (:func:`_append_wide`); every step ends in
+    :func:`_settle_wide`, which compacts when the stack is nearly full and
+    at the last step.
 
     ``st_ref``, the tile's (8, 128) counter block, adds per step: lane 0
-    a live step, lane 1 a step that ran passes, lane 2 the passes."""
+    a live step, lane 1 a step with an entry below the threshold, lane 2
+    the most such entries of a row (the insertion passes, or the entries
+    appended); lane 3 the passes of the buffered merge's compactions."""
     s = pl.program_id(1)
     k2 = od_ref.shape[-1]
+    wide = k2 > NARROW_K2
 
     @pl.when(s == 0)
     def _init():
+        st_ref[...] = jnp.zeros(st_ref.shape, st_ref.dtype)
+        if wide:
+            _init_wide(scratch)
+            return
         od_ref[...] = jnp.full(od_ref.shape, jnp.inf, od_ref.dtype)
         oi_ref[...] = jnp.full(oi_ref.shape, -1, oi_ref.dtype)
         # distinct keys before any real one: an empty slot is the last
+        arr_ref, = scratch
         arr_ref[...] = -1 - jax.lax.broadcasted_iota(jnp.int32,
                                                      arr_ref.shape, 1)
-        st_ref[...] = jnp.zeros(st_ref.shape, st_ref.dtype)
 
     @pl.when(live > 0)
     def _live():
@@ -369,6 +661,10 @@ def _merge_block(scan, live, pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
         ids = jnp.broadcast_to(_pick_row(pids_ref, c).astype(jnp.int32),
                                d.shape)
         d = jnp.where(sel & (ids >= 0), d, jnp.inf)
+        if wide:
+            _append_wide(d, ids, s, st_ref, scratch)
+            return
+        arr_ref, = scratch
         acc_d = od_ref[...]
         thr = jnp.max(acc_d, axis=1, keepdims=True)
         below = jnp.sum((d < thr).astype(jnp.int32), axis=1, keepdims=True)
@@ -389,21 +685,27 @@ def _merge_block(scan, live, pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
                 ((acc_d, oi_ref[...], arr_ref[...]), d))
             od_ref[...], oi_ref[...], arr_ref[...] = acc
 
-    @pl.when(s == pl.num_programs(1) - 1)
+    last = s == pl.num_programs(1) - 1
+    if wide:
+        _settle_wide(last, od_ref, oi_ref, st_ref, scratch)
+        return
+
+    @pl.when(last)
     def _flush():
+        arr_ref, = scratch
         od_ref[...], oi_ref[...] = _sorted_candidates(
             od_ref[...], oi_ref[...], arr_ref[...])
 
 
 def _qtile_topk_kernel(tc_ref, lv_ref, q_ref, pids_ref, qsel_ref, post_ref,
-                       od_ref, oi_ref, st_ref, arr_ref):
+                       od_ref, oi_ref, st_ref, *scratch):
     t = pl.program_id(0)
     s = pl.program_id(1)
     _merge_block(
         lambda: _l2_tile(q_ref[...].astype(jnp.float32),
                          post_ref[0].astype(jnp.float32)),  # one MXU op
         lv_ref[t, s], pids_ref, tc_ref[t, s], qsel_ref,
-        od_ref, oi_ref, st_ref, arr_ref)
+        od_ref, oi_ref, st_ref, scratch)
 
 
 def tile_plan(cids, mask, queries, bq: int, n_clusters: int):
@@ -425,22 +727,36 @@ def tile_plan(cids, mask, queries, bq: int, n_clusters: int):
 
 def topk_call_specs(nb: int, bq: int, k2: int):
     """(out_specs, out_shape, scratch_shapes) of the fused kernels: per
-    tile one (bq, k2) candidate accumulator block for dists and ids and one
-    (8, 128) counter block, each revisited across the tile's probe steps,
-    and the accumulator's (bq, k2) arrival keys in VMEM."""
+    tile one (bq, k2) candidate block for dists and ids and one (8, 128)
+    counter block, each revisited across the tile's probe steps, and the
+    merge's VMEM state: up to ``NARROW_K2`` the (bq, k2) arrival keys of
+    the accumulator the candidate blocks hold; above it the buffered
+    merge's stack of (bq, 128) tiles for distances, ids and keys, its fill
+    point and its per-row threshold."""
     spec = pl.BlockSpec((bq, k2), lambda t, s, *_: (t, 0))
     stat = pl.BlockSpec((8, 128), lambda t, s, *_: (t, 0))
+    if k2 > NARROW_K2:
+        tiles = _stack_tiles(k2) + 1
+        scratch = [pltpu.VMEM((tiles, bq, _LANES), jnp.float32),
+                   pltpu.VMEM((tiles, bq, _LANES), jnp.int32),
+                   pltpu.VMEM((tiles, bq, _LANES), jnp.int32),
+                   pltpu.SMEM((1,), jnp.int32),
+                   pltpu.VMEM((bq, _LANES), jnp.float32)]
+    else:
+        scratch = [pltpu.VMEM((bq, k2), jnp.int32)]
     return ([spec, spec, stat],
             (jax.ShapeDtypeStruct((nb * bq, k2), jnp.float32),
              jax.ShapeDtypeStruct((nb * bq, k2), jnp.int32),
              jax.ShapeDtypeStruct((nb * 8, 128), jnp.int32)),
-            [pltpu.VMEM((bq, k2), jnp.int32)])
+            scratch)
 
 
 def scan_stats(stat_blocks, n_steps: int):
-    """The kernel's (nb * 8, 128) counter blocks as (4,) int32: grid steps,
-    live steps, steps that ran insertion passes, passes."""
-    per_tile = stat_blocks.reshape(-1, 8, 128)[:, 0, :3]
+    """The kernel's (nb * 8, 128) counter blocks as (5,) int32: grid steps,
+    live steps, steps with an entry below the threshold, the entries
+    admitted (insertion passes, or entries appended), and the buffered
+    merge's compaction passes over its stack."""
+    per_tile = stat_blocks.reshape(-1, 8, 128)[:, 0, :4]
     return jnp.concatenate([jnp.full((1,), n_steps, jnp.int32),
                             jnp.sum(per_tile, axis=0)])
 
@@ -458,7 +774,7 @@ def ivf_scan_topk(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused scan + in-kernel top-k2: returns ((B, k2) dists, (B, k2) ids,
-    (4,) int32 counters of :func:`scan_stats`).
+    (5,) int32 counters of :func:`scan_stats`).
 
     Candidates are unique-by-id, ascending by distance, padded with
     (+inf, -1).  Only (B, k2) and the counters cross the pallas_call

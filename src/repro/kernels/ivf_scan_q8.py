@@ -104,7 +104,7 @@ def ivf_scan_q8(
 # --------------------------------------------------------------------------
 def _qtile_topk_q8_kernel(tc_ref, lv_ref, sc_ref, q_ref, cent_ref,
                           norm2_ref, pids_ref, qsel_ref, q8_ref, od_ref,
-                          oi_ref, st_ref, arr_ref):
+                          oi_ref, st_ref, *scratch):
     t = pl.program_id(0)
     s = pl.program_id(1)
     c = tc_ref[t, s]
@@ -114,7 +114,7 @@ def _qtile_topk_q8_kernel(tc_ref, lv_ref, sc_ref, q_ref, cent_ref,
                              q8_ref[0], sc_ref[t, s],
                              _pick_row(norm2_ref, c)),
         lv_ref[t, s], pids_ref, c, qsel_ref, od_ref, oi_ref, st_ref,
-        arr_ref)
+        scratch)
 
 
 @functools.partial(jax.jit, static_argnames=("k2", "bq", "interpret"))
@@ -133,7 +133,7 @@ def ivf_scan_q8_topk(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Fused q8 scan + in-kernel top-k2: ((B, k2) dists, (B, k2) ids,
-    (4,) int32 counters), as ivf_scan_topk.
+    (5,) int32 counters), as ivf_scan_topk.
 
     Same candidate contract as ivf_scan_topk; the per-id min collapses the
     slightly-different residual distances of closure duplicates (each copy is

@@ -179,7 +179,7 @@ def ivf_scan_topk(postings, posting_ids, cids, mask, queries, *, k2, bq=8,
 
     Returns ((B, k2) dists, (B, k2) ids) — the (B, P, L) distance tensor
     never crosses the pallas_call boundary — and, ``with_stats``, the
-    kernel's (4,) int32 counters (``kernels.ivf_scan.scan_stats``)."""
+    kernel's (5,) int32 counters (``kernels.ivf_scan.scan_stats``)."""
     out = _ivf.ivf_scan_topk(postings, posting_ids, cids, mask, queries,
                              k2=k2, bq=bq, interpret=_interp())
     return out if with_stats else out[:2]

@@ -83,6 +83,8 @@ class StageTimes:
                                       # candidate list was exhausted
     rerank_round_size: int = 0     # round width this batch actually used
                                    # (== config unless auto_round adapted it)
+    rerank_rows: int = 0           # f32 rows read from the flash tier
+                                   # for the rounds scored
     # admission (set by ServeEngine at formation; -1/0 outside an engine)
     seq: int = -1                  # batch id: the ``batch`` arg of its
                                    # profiler spans (repro.obs.trace)
@@ -93,8 +95,12 @@ class StageTimes:
     # with the candidates; zeros = the batch ran no fused kernel
     scan_steps: int = 0            # grid steps: query tiles x probe slots
     scan_steps_live: int = 0       # steps whose block some query selects
-    scan_steps_merged: int = 0     # steps that ran insertion passes
-    scan_merge_passes: int = 0     # insertion passes run
+    scan_steps_merged: int = 0     # steps with an entry below the
+                                   # running threshold
+    scan_merge_passes: int = 0     # entries admitted: insertion passes, or
+                                   # entries appended (the most of a row)
+    scan_select_passes: int = 0    # passes of the wide top-k's compactions
+                                   # over its buffer (0 up to NARROW_K2)
 
     @property
     def total(self) -> float:
@@ -151,7 +157,7 @@ class _Inflight:
     size: int
     fresh_seq: int = -1
     queries_host: Optional[np.ndarray] = None
-    stats: Optional[jax.Array] = None   # (4,) fused-kernel counters
+    stats: Optional[jax.Array] = None   # (5,) fused-kernel counters
 
 
 @dataclasses.dataclass(frozen=True)
@@ -160,12 +166,13 @@ class RerankConfig:
 
     Candidates arrive sorted by approximate (q8) distance; re-ranking walks
     them in rounds of ``round_size``, reading the f32 rows from the flash
-    tier and exact-scoring them.  After each round the current exact top-k
-    is compared against the previous round's: once it survives
-    ``stable_rounds`` consecutive rounds unchanged (per the whole batch —
-    the TPU batch is the scheduling unit), further candidates are provably
+    tier and exact-scoring them.  Once the exact top-k survives
+    ``stable_rounds`` consecutive rounds unchanged — no candidate of a
+    round scores at or below its row's k-th exact distance, over the whole
+    batch (the TPU batch is the scheduling unit) — further candidates are
     unlikely to displace it and the walk stops.  ``max_rounds`` caps the
-    walk (0 = only the candidate width bounds it).
+    walk (0 = only the candidate width bounds it); served distances are
+    exact either way.
 
     ``auto_round`` derives the NEXT batch's round width from the stamped
     per-slot flash I/O cost (EWMA over ``rerank_io_s``) so one round's
@@ -202,6 +209,16 @@ def _plan_jit(centroids, llsp_params, queries, topk, cfg: SearchConfig):
     return cids.astype(jnp.int32), nprobe
 
 
+def _kernel_topk(cd, ci, k: int):
+    """Top k of a fused kernel's candidates, which are unique by id and
+    ascending already: their first k columns.  Slicing keeps the served
+    program free of the merge's sorts (at k2 = 2000 they took most of its
+    compile); the merge pads when there are fewer than k."""
+    if cd.shape[1] < k:
+        return merge_candidate_topk(cd, ci, k)
+    return cd[:, :k], ci[:, :k]
+
+
 @functools.partial(jax.jit, static_argnames=("cfg", "dup_bound"))
 def _scan_streamed_jit(packed, packed_ids, remap, pmask, queries,
                        cfg: SearchConfig, *, dup_bound: int):
@@ -219,7 +236,7 @@ def _scan_streamed_jit(packed, packed_ids, remap, pmask, queries,
     cover the build's realized replication or candidates are silently lost —
     PrefetchPipeline derives it from the posting table (max_id_replicas).
 
-    Returns (dists (B, k), ids (B, k), the kernel's (4,) counters or None
+    Returns (dists (B, k), ids (B, k), the kernel's (5,) counters or None
     on the oracle path).
     """
     k2 = cfg.n_cand or _auto_ncand(cfg.k)
@@ -240,7 +257,8 @@ def _scan_streamed_jit(packed, packed_ids, remap, pmask, queries,
         m = min(k2 * dup_bound, r * l)
         nd, pos = topk_smallest(d, m)
         cd, ci = dedup_topk(nd, jnp.take_along_axis(ids, pos, axis=-1), k2)
-    return (*merge_candidate_topk(cd, ci, cfg.k), stats)
+    merge = _kernel_topk if cfg.use_kernel else merge_candidate_topk
+    return (*merge(cd, ci, cfg.k), stats)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "dup_bound"))
@@ -279,7 +297,8 @@ def _scan_streamed_q8_jit(packed_q8, packed_scale, packed_norm2, packed_cent,
         m = min(k2 * dup_bound, r * l)
         nd, pos = topk_smallest(d, m)
         cd, ci = dedup_topk(nd, jnp.take_along_axis(ids, pos, axis=-1), k2)
-    return (*merge_candidate_topk(cd, ci, cfg.k), stats)
+    merge = _kernel_topk if cfg.use_kernel else merge_candidate_topk
+    return (*merge(cd, ci, cfg.k), stats)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
@@ -567,7 +586,8 @@ class PrefetchPipeline:
         if stats is not None:
             (infl.times.scan_steps, infl.times.scan_steps_live,
              infl.times.scan_steps_merged,
-             infl.times.scan_merge_passes) = (int(v) for v in stats)
+             infl.times.scan_merge_passes,
+             infl.times.scan_select_passes) = (int(v) for v in stats)
         quality = None
         if self.flash is not None and infl.size > 0:
             # pre-rerank approximate top-k (candidates arrive ascending by
@@ -597,8 +617,11 @@ class PrefetchPipeline:
         scoring (double-buffered), so flash I/O overlaps the host math the
         same way the prefetch gather overlaps the device scan.  Ids outside
         the flash tier (fresh-delta candidates, already exact) and padding
-        (-1) keep their incoming distance.  Stops once the batch's exact
-        top-k survives ``stable_rounds`` rounds unchanged."""
+        (-1) keep their incoming distance.  Stops once no candidate of
+        ``stable_rounds`` consecutive rounds enters the batch's exact top-k
+        (none scores at or below a row's k-th exact distance).  The top-k
+        is served from scored columns only: when ``max_rounds`` ends the
+        walk before k columns, the rest of the first k are scored too."""
         rc = self.rerank
         k = self.cfg.k
         b, n = cand_i.shape
@@ -619,47 +642,51 @@ class PrefetchPipeline:
                     self.flash.read, cand_i[:, r * step:(r + 1) * step],
                     seq=t.seq)
 
-        prev_top = None
+        def _score(lo, hi, read):
+            uids, rows = read
+            ev = self.flash.stats.events[-1]
+            t.rerank_io_s += ev.end - ev.start
+            t.rerank_rows += int(uids.size)
+            if not uids.size:
+                return
+            cols = cand_i[:, lo:hi]
+            in_flash = (cols >= 0) & (cols < self.flash.n)
+            pos = np.searchsorted(uids, np.clip(cols, 0, None))
+            pos = np.clip(pos, 0, uids.size - 1)
+            hit = in_flash & (uids[pos] == np.clip(cols, 0, None))
+            vecs = rows[pos]                           # (b, w, D)
+            d = np.sum((queries[:, None, :] - vecs) ** 2, axis=-1)
+            exact[:, lo:hi] = np.where(hit, d, exact[:, lo:hi])
+
+        kth = None
         stable = 0
         rounds = 0
         hi = 0
         _submit(0)
         for r in range(n_rounds):
             _submit(r + 1)                 # double-buffer the next read
-            uids, rows = futs.pop(r).result()
-            ev = self.flash.stats.events[-1]
-            t.rerank_io_s += ev.end - ev.start
             lo, hi = r * step, min(n, (r + 1) * step)
-            cols = cand_i[:, lo:hi]
-            in_flash = (cols >= 0) & (cols < self.flash.n)
-            if uids.size:
-                pos = np.searchsorted(uids, np.clip(cols, 0, None))
-                pos = np.clip(pos, 0, uids.size - 1)
-                hit = in_flash & (uids[pos] == np.clip(cols, 0, None))
-                vecs = rows[pos]                       # (b, w, D)
-                d = np.sum((queries[:, None, :] - vecs) ** 2, axis=-1)
-                exact[:, lo:hi] = np.where(hit, d, exact[:, lo:hi])
+            _score(lo, hi, futs.pop(r).result())
             rounds = r + 1
-            # adaptive stop: current exact top-k over the scored prefix
+            # adaptive stop: did this round enter the exact top-k so far?
             if hi >= k:
-                part = np.argpartition(exact[:, :hi], k - 1, axis=1)[:, :k]
-                rowd = np.take_along_axis(exact[:, :hi], part, axis=1)
-                order = np.argsort(rowd, axis=1, kind="stable")
-                sel = np.take_along_axis(part, order, axis=1)
-                top = np.take_along_axis(cand_i[:, :hi], sel, axis=1)
-                if prev_top is not None and np.array_equal(top, prev_top):
+                new = exact[:, lo:hi]
+                if kth is not None and not np.any(
+                        (new <= kth[:, None]) & np.isfinite(new)):
                     stable += 1
                     if stable >= max(int(rc.stable_rounds), 1):
                         t.rerank_stable_stop = hi < n
                         break
                 else:
                     stable = 0
-                prev_top = top
+                kth = np.partition(exact[:, :hi], k - 1, axis=1)[:, k - 1]
         for f in futs.values():            # a speculative read may be queued
             f.cancel()
+        if hi < min(n, k):                 # max_rounds ended the walk early
+            _score(hi, min(n, k), self.flash.read(cand_i[:, hi:k], seq=t.seq))
+            hi = min(n, k)
         # final top-k: exact over the rescored prefix (unvisited tail keeps
         # approx order and, by the stop rule, cannot displace the stable set)
-        hi = max(hi, min(n, k))
         part = np.argpartition(exact[:, :hi], min(k, hi) - 1, axis=1)[:, :k]
         rowd = np.take_along_axis(exact[:, :hi], part, axis=1)
         order = np.argsort(rowd, axis=1, kind="stable")

@@ -326,7 +326,8 @@ def test_gated_merge_matches_ungated(case, kind):
         _assert_candidates_match(gd, gi, wd, wi,
                                  tol=1e-4 if kind == "f32" else 1e-3)
     _, tc, live, _ = tile_plan(cids, mask, q, bq, post.shape[0])
-    steps, n_live, merged, passes = np.asarray(st).tolist()
+    steps, n_live, merged, passes, compaction = np.asarray(st).tolist()
+    assert compaction == 0                 # k2 <= NARROW_K2: no compaction
     assert steps == tc.size
     assert n_live == int(np.asarray(live).sum())
     assert merged <= n_live <= steps
@@ -340,16 +341,19 @@ def test_gated_merge_matches_ungated(case, kind):
 
 @pytest.mark.parametrize("kind", ["f32", "q8"])
 @pytest.mark.parametrize("setup,want", [
-    ("every_block_enters", [6, 3, 3, 12]),
-    ("first_block_fills", [6, 3, 1, 1]),
-    ("all_masked", [6, 0, 0, 0]),
+    ("every_block_enters", [6, 3, 3, 12, 0]),
+    ("first_block_fills", [6, 3, 1, 1, 0]),
+    ("all_masked", [6, 0, 0, 0, 0]),
+    ("wide", [6, 3, 3, 12, 93]),
 ])
 def test_scan_counters_on_a_known_plan(kind, setup, want):
     """Two queries probing clusters 0, 1, 2 in one tile: 6 grid steps, 3
     live blocks of 4 entries.  With k2 above the 12 entries every live step
     merges with 4 passes; with k2 = 1 and each query a vector of cluster 0,
     cluster 0 fills the accumulator with 1 pass and no later entry is below
-    its distance."""
+    its distance.  At k2 = 200 the buffered merge appends the 4 entries of
+    each live step and compacts once, at the tile's last step: two bitonic
+    sorts of 512 positions (45 passes each) and three passes more."""
     rng = np.random.default_rng(5)
     c, l, d = 3, 4, 8
     cents = rng.normal(size=(c, d)).astype(np.float32) * 4.0
@@ -357,7 +361,7 @@ def test_scan_counters_on_a_known_plan(kind, setup, want):
     pids = np.arange(c * l, dtype=np.int32).reshape(c, l)
     cids = np.tile(np.arange(c, dtype=np.int32), (2, 1))
     mask = np.full((2, c), setup != "all_masked")
-    k2 = 1 if setup == "first_block_fills" else 16
+    k2 = {"first_block_fills": 1, "wide": 200}.get(setup, 16)
     q = post[0, :2].copy() if setup == "first_block_fills" \
         else rng.normal(size=(2, d)).astype(np.float32)
     post, cents = jnp.asarray(post), jnp.asarray(cents)
@@ -387,6 +391,97 @@ def test_merge_step_share_reader():
     assert mod.read(ns(batches=[])) is None
     assert mod.read(ns(batches=[ns(scan_steps=0,
                                    scan_steps_merged=0)])) is None
+
+
+# -------------------------------------------------------------------------
+# the top-k at wide k2: the buffered merge against the oracle, exactly
+# -------------------------------------------------------------------------
+def _integer_case(k2):
+    """An exact-arithmetic scan: integer centroids, residual codes (scale
+    1) and queries, so the kernel and the oracle compute the same integer
+    distances bit for bit, with many ties.  Every query probes 40 of 48
+    clusters of 128 slots in ascending cluster order, which makes the
+    oracle's tie order (probe order) the kernel's (arrival order); 10% of
+    the slots are pads, and 300 slots of the second half copy a vector of
+    the first half under its id (closure duplicates)."""
+    rng = np.random.default_rng(7 + k2)
+    c, l, d, b, p = 48, 128, 16, 16, 40
+    cents = rng.integers(-3, 4, size=(c, d)).astype(np.float32)
+    q8 = rng.integers(-2, 3, size=(c, l, d)).astype(np.int8)
+    pids = np.arange(c * l, dtype=np.int32).reshape(c, l)
+    pids[rng.random((c, l)) < 0.1] = -1
+    src = (rng.integers(0, c // 2, size=300), rng.integers(0, l, size=300))
+    dst = (rng.integers(c // 2, c, size=300), rng.integers(0, l, size=300))
+    pids[dst] = pids[src]
+    q8[dst] = (cents[src[0]] + q8[src] - cents[dst[0]]).astype(np.int8)
+    post = cents[:, None, :] + q8.astype(np.float32)
+    q = rng.integers(-3, 4, size=(b, d)).astype(np.float32)
+    cids = np.sort(np.stack([rng.choice(c, size=p, replace=False)
+                             for _ in range(b)]), axis=1).astype(np.int32)
+    mask = rng.random((b, p)) < 0.9
+    scale = np.ones((c, 1, 1), np.float32)
+    norm2 = (q8.astype(np.float32) ** 2).sum(-1)
+    payload = {"f32": (jnp.asarray(post),),
+               "q8": tuple(map(jnp.asarray, (q8, scale, norm2, cents)))}
+    probe = tuple(map(jnp.asarray, (pids, cids, mask, q)))
+    return payload, probe
+
+
+@pytest.mark.parametrize("kind", ["f32", "q8"])
+@pytest.mark.parametrize("k2", [24, 200, 2000])
+def test_topk_equals_oracle_at_width(kind, k2):
+    """Both fused kernels emit exactly the oracle's candidates, distances
+    and ids in order, at the narrow width and on both sides of the buffered
+    merge's compactions (k2 = 200: many; k2 = 2000: few), with ties and
+    closure duplicates.  Only the buffered merge counts compaction
+    passes."""
+    from repro.kernels.ivf_scan import NARROW_K2
+
+    payload, probe = _integer_case(k2)
+    gd, gi, st = _gated(kind, payload[kind], *probe, k2=k2, bq=8)
+    wd, wi = _oracle(kind, payload[kind], *probe, k2=k2)
+    np.testing.assert_array_equal(np.asarray(gd), np.asarray(wd))
+    np.testing.assert_array_equal(np.asarray(gi), np.asarray(wi))
+    steps, live, merged, admitted, compaction = np.asarray(st).tolist()
+    assert merged <= live <= steps and merged <= admitted
+    assert (compaction > 0) == (k2 > NARROW_K2)
+
+
+def _reader(name):
+    import importlib.util
+    import os
+
+    path = os.path.join(os.path.dirname(__file__), "..", "bench", "metrics",
+                        f"{name}.py")
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"),
+                                                  path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.read
+
+
+@pytest.mark.parametrize("name,field,base,want", [
+    ("topk.select_passes_per_live_step", "scan_select_passes",
+     "scan_steps_live", (93 + 279) / (40 + 60)),
+    ("rerank.rows_per_query", "rerank_rows", "size", (93 + 279) / (40 + 60)),
+])
+def test_wide_topk_readers(name, field, base, want):
+    """The wide top-k's counter readers: a ratio of summed stamps, and
+    nothing from batches that lack the stamp (a program without it) or
+    that counted none."""
+    import types
+
+    read = _reader(name)
+    ns = types.SimpleNamespace
+
+    def batch(v, n):
+        return ns(**{field: v, base: n, "size": n})
+
+    assert read(ns(batches=[batch(93, 40), batch(279, 60)])) \
+        == pytest.approx(want)
+    assert read(ns(batches=[ns(size=3, scan_steps_live=3)])) is None
+    assert read(ns(batches=[])) is None
+    assert read(ns(batches=[batch(0, 0)])) is None
 
 
 # -------------------------------------------------------------------------

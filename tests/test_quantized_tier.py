@@ -217,6 +217,77 @@ def test_q8_adaptive_stop_stable_topk(small_index, small_corpus, tmp_path):
     np.testing.assert_allclose(ra.dists, rf.dists, rtol=1e-5, atol=1e-5)
 
 
+def test_q8_rerank_capped_walk_serves_exact_distances(small_index,
+                                                      small_corpus, tmp_path):
+    """When ``max_rounds`` ends the walk before k candidates were scored,
+    the rest of the first k are scored before serving: every served id in
+    the flash tier carries its exact f32 distance, none a q8 one."""
+    x, q, topk = small_corpus
+    pipe = make_quantized_pipeline(
+        small_index, None, CFG, vectors=x,
+        flash_path=str(tmp_path / "cap.f32"), pad_batch=8, row_bucket=32,
+        rerank=RerankConfig(round_size=4, max_rounds=1))
+    res = pipe.serve_batch(q[:16], topk[:16])
+    assert res.times.rerank_rounds == 1
+    assert res.times.rerank_cands == CFG.k
+    live = res.ids >= 0
+    want = ((q[:16, None, :].astype(np.float64)
+             - x[np.maximum(res.ids, 0)].astype(np.float64)) ** 2).sum(-1)
+    np.testing.assert_allclose(res.dists[live], want[live], rtol=1e-5)
+    assert np.all(np.diff(res.dists, axis=1) >= 0)
+
+
+@pytest.fixture(scope="module")
+def search_shaped():
+    """A seeded 20,000 x 64-d corpus of 16 modes, clustered into lists of
+    <= 96 primaries in 128 slots with closure replicas, and 16 queries."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from repro.build.kmeans import balanced_hierarchical_kmeans
+    from repro.core.ivf import IVFIndex, build_postings
+    from repro.core.spann_rules import closure_assign
+    from repro.data import PAPER_DATASETS, make_queries, make_vectors
+
+    spec = dataclasses.replace(PAPER_DATASETS["redsrch"], n=20_000, dim=64,
+                               n_modes=16, seed=11)
+    x = make_vectors(spec)
+    q, _ = make_queries(spec, 16)
+    cents, _ = balanced_hierarchical_kmeans(x, max_cluster_size=96, iters=8)
+    ca = np.asarray(closure_assign(jnp.asarray(x), jnp.asarray(cents),
+                                   eps=0.2, max_replicas=4))
+    postings, pids = build_postings(x, ca, cents.shape[0], 128)
+    index = IVFIndex(jnp.asarray(cents), jnp.asarray(postings),
+                     jnp.asarray(pids))
+    return x, q, index
+
+
+def test_q8_pipeline_serves_top_1000(search_shaped, tmp_path):
+    """The served q8 path at k = 1000 (fused kernel at k2 = 2000, its
+    buffered merge, the 2000-wide re-rank) against float32 brute force:
+    recall@1000 >= 0.95, every served distance the exact one of its id,
+    no answer malformed."""
+    x, q, index = search_shaped
+    cfg = SearchConfig(k=1000, nprobe_max=64, pruning="none",
+                       use_kernel=True, fused_topk=True)
+    pipe = make_quantized_pipeline(index, None, cfg, vectors=x,
+                                   flash_path=str(tmp_path / "s.f32"),
+                                   pad_batch=16, row_bucket=256)
+    res = pipe.serve_batch(q, 1000)
+    _, truth = brute_force_topk(jnp.asarray(x), jnp.asarray(q), 1000)
+    assert res.ids.shape == (16, 1000)
+    assert recall_at_k(res.ids, np.asarray(truth)) >= 0.95
+    assert np.all(res.ids >= 0)
+    assert all(len(set(r.tolist())) == 1000 for r in res.ids)
+    assert np.all(np.diff(res.dists, axis=1) >= 0)
+    want = ((q[:, None, :].astype(np.float64)
+             - x[res.ids].astype(np.float64)) ** 2).sum(-1)
+    np.testing.assert_allclose(res.dists, want, rtol=1e-5)
+    t = res.times
+    assert t.scan_select_passes > 0        # the buffered merge compacted
+    assert 1000 <= t.rerank_rows <= 2000 * 16    # deduplicated per round
+
+
 def test_no_rerank_arm_serves_quantized_distances(small_index, small_corpus):
     """``with_flash=False`` (--no-rerank) serves raw q8 first-pass results:
     no rerank stamps, tier still quantized."""

@@ -241,9 +241,10 @@ def test_kernel_pipeline_stamps_scan_counters(small_index, queries,
     k2 = 16                                # the auto candidate width at k=5
     assert t.scan_steps_merged <= t.scan_merge_passes \
         <= k2 * t.scan_steps_merged
+    assert t.scan_select_passes == 0       # k2 <= NARROW_K2: no buffer
     o = times[False]
     assert (o.scan_steps, o.scan_steps_live, o.scan_steps_merged,
-            o.scan_merge_passes) == (0, 0, 0, 0)
+            o.scan_merge_passes, o.scan_select_passes) == (0, 0, 0, 0, 0)
 
 
 # -------------------------------------------------------------------------
