@@ -68,7 +68,7 @@ POLLER_THREAD = "serve-poller"     # ServeEngine's poller
 GATHER_THREAD = "prefetch"         # PrefetchPipeline's gather worker (prefix)
 RERANK_THREAD = "rerank"           # PrefetchPipeline's flash-read worker
 
-SPAN_IDLE = "serve.idle"           # poller asleep: nothing prepared or in flight
+SPAN_IDLE = "serve.idle"           # poller asleep: no work pending anywhere
 SPAN_FORM = "serve.form"           # batcher.form + epoch routing
 SPAN_PLAN = "serve.plan"           # PrefetchPipeline.plan
 SPAN_WAIT_GATHER = "serve.wait_gather"  # dispatch joins the gather worker

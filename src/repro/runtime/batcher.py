@@ -7,7 +7,10 @@ runtime makes in its userspace stack:
 
 * **coalescing** — accumulate single-query arrivals per index and release a
   micro-batch when it is full (``max_batch``) or its head-of-line request
-  has waited ``max_wait_s`` (bounded batching delay);
+  has waited ``max_wait_s`` (bounded batching delay); a poller with nothing
+  prepared and nothing in flight releases any non-empty queue at once
+  (``form(idle=True)``: work-conserving — a request waits for the batcher
+  only while the chip or the host is busy with an earlier batch);
 * **locality grouping** — the packed scan distances every query in a batch
   against the batch's whole probed-cluster *union*, and the host tier
   gathers that union per batch; a batch of queries that probe the same
@@ -96,6 +99,8 @@ class MicroBatch:
     waits: Optional[np.ndarray] = None   # (b,) seconds in queue at formation
     probe_union: Optional[frozenset] = None  # union of admission-time probe
                                              # sets (None: no routed request)
+    released_idle: bool = False    # released by the idle rule: the queue
+                                   # was neither full nor aged
 
 
 @dataclasses.dataclass
@@ -109,6 +114,7 @@ class BatcherStats:
     aged_seeds: int = 0            # requests force-seeded by the aging guard
     max_queue_wait_s: float = 0.0  # worst formation wait seen (aging bound
                                    # evidence: compare against max_wait_s)
+    idle_releases: int = 0         # batches released early by form(idle=True)
 
 
 def _probe_set(req: SearchRequest) -> frozenset:
@@ -205,18 +211,19 @@ class DynamicBatcher:
         """Is some index due for release (full batch or head-of-line aged)?"""
         return any(self._due(q, now) for q in self._pending.values())
 
-    def _pick_index(self, now: float, force: bool) -> Optional[str]:
-        """Round-robin scan from the cursor; ``force`` takes any non-empty
-        queue (drain path).  Advancing the cursor by scan offset — never by
-        name lookup — keeps the drain order a deterministic function of
-        (queue state, cursor), independent of how indexes were added."""
+    def _pick_index(self, now: float, any_queue: bool) -> Optional[str]:
+        """Round-robin scan from the cursor; ``any_queue`` takes any
+        non-empty queue (drain path, idle release).  Advancing the cursor
+        by scan offset — never by name lookup — keeps the drain order a
+        deterministic function of (queue state, cursor), independent of
+        how indexes were added."""
         names = list(self._pending)
         for off in range(len(names)):
             name = names[(self._rr + off) % len(names)]
             q = self._pending[name]
             if not q:
                 continue
-            if force or self._due(q, now):
+            if any_queue or self._due(q, now):
                 self._rr = (self._rr + off + 1) % len(names)
                 return name
         return None
@@ -350,7 +357,7 @@ class DynamicBatcher:
         return keep, cap, deg, sheds
 
     def form(
-        self, now: float, force: bool = False
+        self, now: float, force: bool = False, idle: bool = False
     ) -> tuple[Optional[MicroBatch], list[Completion]]:
         """Release the next micro-batch (round-robin across indexes).
 
@@ -358,12 +365,19 @@ class DynamicBatcher:
         formation time because even the degraded path would miss their
         deadline.  ``force`` releases a partial batch regardless of age, in
         strict FIFO order (drain/shutdown path — deterministic regardless of
-        grouping mode).
+        grouping mode).  ``idle`` (the caller has nothing prepared and
+        nothing in flight) makes every non-empty queue due, so a partial
+        batch leaves at once instead of waiting out ``max_wait_s`` on an
+        idle chip; selection is the normal one (locality packing, aging
+        guard), and a batch the old predicate would not have released is
+        marked ``released_idle``.
         """
-        pick = self._pick_index(now, force)
+        pick = self._pick_index(now, force or idle)
         if pick is None:
             return None, []
-        reqs = self._select(pick, self._pending[pick], now, force)
+        q = self._pending[pick]
+        early = idle and not force and not self._due(q, now)
+        reqs = self._select(pick, q, now, force)
         keep, cap, deg, shed_reqs = self._admit(reqs, now)
         sheds = []
         for r in shed_reqs:
@@ -383,8 +397,9 @@ class DynamicBatcher:
         if any(_probe_set(r) for r in keep):
             union = frozenset().union(*[_probe_set(r) for r in keep])
         self.stats.batches += 1
+        self.stats.idle_releases += int(early)
         return MicroBatch(
             index=pick, requests=keep,
             nprobe_cap=cap, degraded=deg, formed_at=now,
-            waits=waits, probe_union=union,
+            waits=waits, probe_union=union, released_idle=early,
         ), sheds

@@ -526,19 +526,22 @@ class ServeEngine:
         for name, a, b in spans:
             tr.span(name, a, b, track=lane)
 
-    def _form_and_plan(self, now: float, force: bool = False):
+    def _form_and_plan(self, now: float, force: bool = False,
+                       idle: bool = False):
         """Form the next micro-batch and run its plan stage (device idle
         here by construction — before the current batch's scan dispatch).
+        ``idle``: the poller has nothing prepared and nothing in flight, so
+        the batcher releases any pending request (``DynamicBatcher.form``).
 
         Epoch routing happens HERE: the batch takes an in-flight reference
         on the current epoch and carries it to harvest, so a concurrent
         swap cannot re-route (or early-retire) a batch mid-flight."""
         with region(SPAN_FORM) as span:
-            mb, sheds = self.batcher.form(now, force=force)
+            mb, sheds = self.batcher.form(now, force=force, idle=idle)
             epoch = None
             if mb is not None:
                 seq = next(self._batch_ids)
-                span.set_metadata(batch=seq)
+                span.set_metadata(batch=seq, idle=int(mb.released_idle))
                 if self.versions is not None:
                     epoch = self.versions.route(mb.index)
         if sheds:
@@ -578,6 +581,7 @@ class ServeEngine:
             t.seq = seq
             t.formed_at = mb.formed_at
             t.queue_wait_s = float(mb.waits.sum())
+            t.released_idle = mb.released_idle
         if self.obs.tracing:
             # sampled request identities ride the plan into the fabric so
             # every shard task (incl. requeue/hedge) tags its queries
@@ -700,7 +704,10 @@ class ServeEngine:
                 # search cadence holds
                 self._pump_updates(now)
                 if prep is None:
-                    planned = self._form_and_plan(now)
+                    # nothing prepared; with nothing in flight either, any
+                    # pending request leaves now rather than after
+                    # max_wait_s on an idle chip (work-conserving release)
+                    planned = self._form_and_plan(now, idle=not inflight)
                     if planned is not None:
                         prep = self._prep_or_fail(planned)
                         continue       # give the SQ one more drain pass

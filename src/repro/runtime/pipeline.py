@@ -91,6 +91,8 @@ class StageTimes:
     formed_at: float = 0.0         # batcher release time (engine clock)
     queue_wait_s: float = 0.0      # its requests' summed wait from submit
                                    # to formation (MicroBatch.waits)
+    released_idle: bool = False    # released early by the batcher's idle
+                                   # rule (MicroBatch.released_idle)
     # fused scan kernel counters (kernels.ivf_scan.scan_stats), read back
     # with the candidates; zeros = the batch ran no fused kernel
     scan_steps: int = 0            # grid steps: query tiles x probe slots

@@ -564,8 +564,8 @@ def test_queue_wait_is_the_batchers_summed_waits():
     formed = []
     form = eng.batcher.form
 
-    def spy(now, force=False):
-        mb, sheds = form(now, force=force)
+    def spy(now, force=False, idle=False):
+        mb, sheds = form(now, force=force, idle=idle)
         if mb is not None:
             formed.append(mb)
         return mb, sheds
@@ -594,3 +594,4 @@ def test_queue_wait_is_the_batchers_summed_waits():
         assert t.queue_wait_s == pytest.approx(float(mb.waits.sum()))
     assert times[0].queue_wait_s == pytest.approx(0.026)
     assert times[1].queue_wait_s == pytest.approx(0.008)
+    assert [t.released_idle for t in times] == [False, False]

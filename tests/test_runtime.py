@@ -7,6 +7,9 @@ times) only, so replaying a seeded trace must reproduce the decision
 sequence bit-for-bit (``BatchPolicy(ewma=0)`` freezes the service-time
 estimate — the one input that otherwise comes from wall-clock measurement).
 """
+import threading
+import time
+
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -312,6 +315,22 @@ def test_ready_and_form_share_due_predicate():
     second, _ = b.form(0.03, force=True)
     assert {first.index, second.index} == {"a", "b"}
     assert b.form(0.03, force=True) == (None, [])
+    assert not first.released_idle and b.stats.idle_releases == 0
+    # idle release: a young, underfull queue leaves at once under
+    # form(idle=True), while ready() and form() without idle still hold it
+    b.add(_req(30, None, t=0.04), now=0.04)
+    assert not b.ready(0.041)
+    assert b.form(0.041) == (None, [])
+    mb, _ = b.form(0.041, idle=True)
+    assert [r.req_id for r in mb.requests] == [30] and mb.released_idle
+    assert b.stats.idle_releases == 1
+    # a queue the old predicate releases anyway is no idle release
+    b.add(_req(31, None, t=0.05), now=0.05)
+    assert b.ready(0.07)
+    mb, _ = b.form(0.07, idle=True)
+    assert [r.req_id for r in mb.requests] == [31] and not mb.released_idle
+    assert b.stats.idle_releases == 1
+    assert b.form(0.07, idle=True) == (None, [])     # nothing pending
 
 
 def test_locality_grouping_packs_by_probe_overlap():
@@ -374,6 +393,31 @@ def test_union_growth_cap_releases_tight_partial_batches():
     assert [r.req_id for r in mb.requests] == [0, 1, 3]   # outlier deferred
     mb2, _ = b.form(10.5)                              # ages, then releases
     assert [r.req_id for r in mb2.requests] == [2]
+
+
+def test_idle_release_keeps_locality_and_aging_guard():
+    """``form(idle=True)`` only makes the queue due: the batch is still
+    packed by probe overlap from the head of the line, and a request aged
+    past ``max_wait_s`` still seeds it."""
+    policy = BatchPolicy(max_batch=4, max_wait_s=0.01, shed="none",
+                         union_growth_cap=1)
+    b = DynamicBatcher(policy, ["a"])
+    b.add(_routed_req(0, (1, 2, 3), t=0.0), now=0.0)
+    b.add(_routed_req(1, (50, 51, 52), t=0.0), now=0.0)   # the outlier
+    b.add(_routed_req(2, (1, 2, 4), t=0.0), now=0.0)
+    mb, _ = b.form(0.001, idle=True)
+    assert [r.req_id for r in mb.requests] == [0, 2]      # packed, capped
+    assert mb.released_idle and b.stats.aged_seeds == 0
+    # the outlier ages while two young requests arrive: it must seed the
+    # next batch although it would grow the union most
+    b.add(_routed_req(3, (1, 2, 3), t=0.009), now=0.009)
+    b.add(_routed_req(4, (1, 2, 3), t=0.009), now=0.009)
+    mb, _ = b.form(0.011, idle=True)
+    assert [r.req_id for r in mb.requests] == [1]
+    assert not mb.released_idle and b.stats.aged_seeds == 1
+    mb, _ = b.form(0.011, idle=True)
+    assert [r.req_id for r in mb.requests] == [3, 4] and mb.released_idle
+    assert b.stats.idle_releases == 2
 
 
 # -------------------------------------------------------------------------
@@ -709,27 +753,138 @@ def test_harvest_fault_completes_batch_as_failed(small_index, queries):
     assert sum(1 for c in comps if c.status == "ok") == n - n_failed
 
 
-def test_stop_without_drain_sheds_instead_of_abandoning(small_index,
-                                                        queries):
+class _GatedPipe:
+    """Stage-protocol pipeline that holds its first batch in flight: that
+    batch's ``dispatch`` calls ``on_first_dispatch`` (requests arriving
+    while the chip is busy) and its ``harvest`` waits for ``gate``.  Logs
+    every planned batch as (size, batches harvested before its plan)."""
+    pad_batch = 8
+
+    def __init__(self):
+        self.on_first_dispatch = lambda: None
+        self.gate = threading.Event()
+        self.holding = threading.Event()
+        self.log = []
+        self.harvested = 0
+
+    def plan(self, queries, topk, nprobe_cap=None, routed=None):
+        self.log.append((queries.shape[0], self.harvested))
+        return queries.shape[0]
+
+    def prefetch(self, b):
+        return b
+
+    def dispatch(self, b):
+        if len(self.log) == 1:
+            self.on_first_dispatch()
+        return b
+
+    def harvest(self, b):
+        if self.harvested == 0:
+            self.holding.set()
+            self.gate.wait(timeout=10.0)
+        self.harvested += 1
+        return BatchResult(
+            ids=np.zeros((b, 5), np.int32),
+            dists=np.zeros((b, 5), np.float32),
+            nprobe=np.full(b, 1, np.int32), times=StageTimes(size=b))
+
+
+def _gated_engine():
+    """A poller over a ``_GatedPipe`` whose ``max_wait_s`` (2 s) no test
+    waits out: a batch leaves by the idle rule or not at all."""
+    pipe = _GatedPipe()
+    eng = ServeEngine({"a": pipe},
+                      DynamicBatcher(BatchPolicy(max_batch=16, max_wait_s=2.0,
+                                                 pad=8), ["a"]),
+                      clock=time.monotonic, depth=2)
+    return eng, pipe
+
+
+def _submit(eng, n):
+    return sum(eng.submit(np.zeros(4, np.float32), 5, index="a") >= 0
+               for _ in range(n))
+
+
+def test_stop_without_drain_sheds_instead_of_abandoning():
     """Regression: ``stop(drain=False)`` used to abandon requests pooled
     in the batcher (and SQ residents) — no CQ entry, blocked clients.
-    Now every admitted-but-unformed request completes as "shed"."""
-    q, _ = queries
-    import time as _time
-    # max_wait long enough that the batch cannot become due before stop
-    policy = BatchPolicy(max_batch=64, max_wait_s=0.2, pad=8)
-    eng = _mk_engine(small_index, policy=policy)
-    eng.clock = _time.monotonic
+    Now every admitted-but-unformed request completes as "shed".  An idle
+    poller releases a request at once, so the first batch is held in
+    flight: three requests arrive at its dispatch (the poller drains them
+    into the batcher's pool), two more while its harvest waits (the SQ)."""
+    eng, pipe = _gated_engine()
+    n_pooled = []
+    pipe.on_first_dispatch = lambda: n_pooled.append(_submit(eng, 3))
     eng.start()
-    n = 0
-    for i in range(5):
-        n += eng.submit(q[i], 5, index="idx0") >= 0
-    _time.sleep(0.05)
-    eng.stop(drain=False)
+    n = _submit(eng, 1)
+    assert pipe.holding.wait(timeout=10.0)
+    n += _submit(eng, 2) + sum(n_pooled)
+    stopper = threading.Thread(target=eng.stop, kwargs={"drain": False})
+    stopper.start()
+    assert eng._stop.wait(timeout=10.0)
+    pipe.gate.set()
+    stopper.join(timeout=10.0)
+    assert not stopper.is_alive()
     comps = eng.qp.poll()
-    assert len(comps) == n == eng.stats.completed
-    assert {c.status for c in comps} == {"shed"}
+    assert len(comps) == n == 6 == eng.stats.completed
+    assert [c.status for c in comps] == ["ok"] + ["shed"] * 5
     assert eng.batcher.pending() == 0
+
+
+def test_idle_engine_releases_lone_request_at_once(small_index, queries):
+    """An idle poller releases a lone request without waiting out
+    ``max_wait_s`` (2 s), and stamps the batch ``released_idle``."""
+    q, _ = queries
+    eng = _mk_engine(small_index, n_indexes=1,
+                     policy=BatchPolicy(max_batch=16, max_wait_s=2.0, pad=8))
+    eng.clock = time.monotonic
+    eng.submit(q[0], 5, index="idx0")
+    # due by age at this clock: routes, plans and serves the batch's
+    # shapes once, so the threaded run compiles nothing
+    assert eng.step(now=time.monotonic() + 3.0, force=False) == 1
+    eng.qp.poll()
+    stamps = []
+    orig = eng._complete_batch
+
+    def spy(mb, result, done, epoch=None):
+        stamps.append(result.times.released_idle)
+        orig(mb, result, done, epoch=epoch)
+
+    eng._complete_batch = spy
+    eng.start()
+    try:
+        eng.submit(q[0], 5, index="idx0")
+        assert eng.qp.wait_completions(1, timeout=10.0)
+        (c,) = eng.qp.poll()
+    finally:
+        eng.stop()
+    assert c.status == "ok"
+    assert c.latency < 1.0
+    assert stamps == [True] and eng.batcher.stats.idle_releases == 1
+
+
+def test_busy_engine_coalesces_until_harvest():
+    """While a batch is in flight the old predicate holds: requests that
+    arrive meanwhile are not released early (``max_wait_s`` is 2 s), and
+    form one batch once the harvest has freed the poller."""
+    eng, pipe = _gated_engine()
+    pipe.on_first_dispatch = lambda: _submit(eng, 3)
+    eng.start()
+    try:
+        assert _submit(eng, 1) == 1
+        assert pipe.holding.wait(timeout=10.0)
+        assert _submit(eng, 2) == 2
+        time.sleep(0.1)
+        assert pipe.log == [(1, 0)]        # nothing formed while in flight
+        pipe.gate.set()
+        assert eng.qp.wait_completions(6, timeout=10.0)
+    finally:
+        pipe.gate.set()
+        eng.stop()
+    assert pipe.log == [(1, 0), (5, 1)]
+    assert {c.status for c in eng.qp.poll()} == {"ok"}
+    assert eng.batcher.stats.idle_releases == 2
 
 
 def test_batcher_drain_pending_fifo():
